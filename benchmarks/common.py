@@ -163,11 +163,13 @@ def _run_grid(grid) -> List[float]:
     workers = min(_BENCH_PROCS, len(grid))
     if workers > 1 and _grid_picklable(grid):
         import concurrent.futures as cf
-        try:
-            with cf.ProcessPoolExecutor(max_workers=workers) as ex:
-                return list(ex.map(_slowdown_cell, grid))
-        except (OSError, cf.process.BrokenProcessPool):
-            pass  # restricted environment: fall through to serial
+        import multiprocessing as mp
+        # Spawned, never forked: the caller may already hold JAX (and the
+        # chip). A broken pool raises.
+        with cf.ProcessPoolExecutor(
+                max_workers=workers,
+                mp_context=mp.get_context("spawn")) as ex:
+            return list(ex.map(_slowdown_cell, grid))
     return [_slowdown_cell(cell) for cell in grid]
 
 

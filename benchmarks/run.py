@@ -1,10 +1,15 @@
-"""Benchmark aggregator: one module per paper figure, plus the dry-run
-roofline summary. Prints ``name,value,derived`` CSV rows.
+"""Benchmark aggregator: one module per paper figure and perf harness.
+Prints ``name,value,derived`` CSV rows.
 
 ``--jobs N`` fans the figure modules out over N worker processes. Rows
 are still printed in the canonical ``FIGS`` order (results are collected
 per module and emitted in submission order), so the CSV is deterministic
-regardless of completion order.
+regardless of completion order. Workers are spawned, never forked: the
+serial modules initialise JAX in this process, and a forked child of a
+process that holds the chip fails or hangs. A broken pool raises.
+
+JAX's persistent compilation cache is on for every run
+(:mod:`repro.compile_cache`).
 
 Usage:
     PYTHONPATH=src python -m benchmarks.run             # all figures
@@ -78,6 +83,8 @@ def main() -> None:
                     help="run figure modules across N processes "
                          "(CSV row order stays deterministic)")
     args = ap.parse_args()
+    from repro.compile_cache import enable
+    enable()
     if args.quick:
         os.environ["REPRO_BENCH_QUICK"] = "1"
     selected = FIGS
@@ -97,15 +104,13 @@ def main() -> None:
     by_mod = {}
     if jobs > 1 and len(parallel) > 1:
         import concurrent.futures as cf
+        import multiprocessing as mp
         inner = max(1, (os.cpu_count() or 1) // jobs)
-        try:
-            with cf.ProcessPoolExecutor(max_workers=jobs) as ex:
-                futs = {m: ex.submit(_run_module, m, args.quick, inner)
-                        for m in parallel}
-                by_mod = {m: f.result() for m, f in futs.items()}
-        except (OSError, ImportError, cf.process.BrokenProcessPool):
-            # restricted environment (no fork/sem): serial fallback
-            by_mod = {}
+        with cf.ProcessPoolExecutor(
+                max_workers=jobs, mp_context=mp.get_context("spawn")) as ex:
+            futs = {m: ex.submit(_run_module, m, args.quick, inner)
+                    for m in parallel}
+            by_mod = {m: f.result() for m, f in futs.items()}
 
     def emit(mod_name, outcome):
         rows, wall, err = outcome

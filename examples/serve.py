@@ -11,6 +11,8 @@ loss) or a step wedges past its retries.
     PYTHONPATH=src python examples/serve.py --policy bino --chaos crash
     PYTHONPATH=src python examples/serve.py --policy restart \
         --chaos "drop:1:0.1:0.5,dup:0:0.05:0.9" --steps 6
+    # the full-width model (the path chip_smoke.py phase (d) drives):
+    PYTHONPATH=src python examples/serve.py --full --chaos crash
 
 Exit codes: 0 ok, 2 corrupted model update, 3 wedged (retries exhausted).
 """
@@ -44,6 +46,9 @@ def _update_corrupted(trainer) -> bool:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=ARCH_IDS)
+    ap.add_argument("--full", action="store_true",
+                    help="use the full production config (not reduced); "
+                         "sized for one accelerator, slow on a CPU")
     ap.add_argument("--steps", type=int, default=4)
     ap.add_argument("--hosts", type=int, default=4)
     ap.add_argument("--microbatches", type=int, default=4)
@@ -61,7 +66,9 @@ def main() -> int:
                          "see examples/TRACES.md)")
     args = ap.parse_args()
 
-    cfg = reduced_config(get_config(args.arch))
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = reduced_config(cfg)
     chaos = (ChaosController(parse_script(args.chaos),
                              horizon=args.horizon, seed=args.seed)
              if args.chaos else None)

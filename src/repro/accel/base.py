@@ -12,16 +12,17 @@ Three implementations ship:
 - ``numpy`` (:mod:`repro.accel.numpy_backend`) — the PR-1 columnar path,
   verbatim. The bit-exact reference; zero new dependencies.
 - ``jax`` (:mod:`repro.accel.jax_backend`) — jit-compiled kernels over
-  padded device mirrors (:class:`repro.core.arrays.DeviceColumns`),
-  float64 via a scoped ``enable_x64`` so CPU runs match numpy bit-exactly.
+  padded device mirrors (:class:`repro.core.arrays.DeviceColumns`):
+  float64 on the CPU (a scoped ``jax.enable_x64(True)``) so runs match
+  numpy bit-exactly, float32 on a TPU.
 - ``pallas`` (:mod:`repro.accel.pallas_backend`) — hand-written Pallas
-  kernels for the two hottest reductions (glance and LATE/collective
-  segment passes), ``interpret=True`` by default so CI runs without a
-  TPU/GPU.
+  kernels for the segmented reductions (glance, temporal, LATE,
+  collective, reap), compiled on a TPU and interpreted elsewhere.
 
-The equivalence contract — which results are bit-exact and where f32
-device math waives exactness — is DESIGN.md §13.3, gated by
-``tests/test_accel.py``.
+The equivalence contract — bit-exact on the CPU, decisions within a
+stated tolerance on a TPU — is DESIGN.md §13.3, gated by
+``tests/test_accel.py`` and checked on the chip by
+:mod:`repro.accel.shadow`.
 """
 from __future__ import annotations
 

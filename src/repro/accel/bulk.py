@@ -14,12 +14,12 @@ Mirroring the :class:`repro.accel.base.AssessmentBackend` discipline,
 three implementations ship behind one protocol:
 
 - ``numpy`` — the bit-exact reference (the PR 5 solver loop, verbatim);
-- ``jax`` — the same rounds as a jit ``lax.while_loop`` in scoped
-  float64; per-round link loads are scatter-adds of exact small
+- ``jax`` — the same rounds as a jit ``lax.while_loop``, in the
+  platform's precision (scoped float64 on the CPU, float32 on a TPU;
+  §13.3); per-round link loads are scatter-adds of exact small
   integers, so CPU runs match numpy bit-for-bit;
-- ``pallas`` — jax water-fill plus a hand-written Pallas pricing kernel
-  (``interpret=True`` by default; ``REPRO_PALLAS_COMPILE=1`` lowers to
-  a real device).
+- ``pallas`` — the jax steps: neither has a Pallas kernel yet
+  (:class:`PallasBulk`).
 
 Backends are resolved lazily (:func:`get_bulk_backend`) so the numpy
 path never pays jax import cost; the network layer stays import-clean
@@ -141,18 +141,19 @@ class JaxBulk(BulkBackend):
         if not k:
             return eff.copy(), np.zeros(0)
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.accel.jax_backend import precision, scalar
         cap = self._pad(k)
         L = np.zeros((cap, 4), dtype=np.int32)
         V = np.zeros((cap, 4), dtype=bool)
         L[:k] = np.where(valid, links, 0)
         V[:k] = valid
-        with enable_x64():
+        with precision():
             fn = self._fill_fn(len(eff), cap, float(eps))
-            share, rate = fn(jnp.asarray(eff, jnp.float64),
-                             jnp.asarray(L), jnp.asarray(V),
-                             jnp.float64(1.0))
-            return np.asarray(share), np.asarray(rate)[:k]
+            one = scalar(1.0)
+            share, rate = fn(jnp.asarray(eff, one.dtype), jnp.asarray(L),
+                             jnp.asarray(V), one)
+            return (np.asarray(share, np.float64),
+                    np.asarray(rate, np.float64)[:k])
 
     def _price_core(self):
         import jax
@@ -169,7 +170,7 @@ class JaxBulk(BulkBackend):
         if not k:
             return np.zeros(0)
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.accel.jax_backend import precision, scalar
         cap = self._pad(k)
         L = np.zeros((cap, 4), dtype=np.int32)
         V = np.zeros((cap, 4), dtype=bool)
@@ -178,10 +179,10 @@ class JaxBulk(BulkBackend):
         fn = self._prices.get("price")
         if fn is None:
             fn = self._prices["price"] = self._price_core()
-        with enable_x64():
-            out = fn(jnp.asarray(share, jnp.float64), jnp.asarray(L),
+        with precision():
+            out = fn(jnp.asarray(share, scalar(0.0).dtype), jnp.asarray(L),
                      jnp.asarray(V))
-            return np.asarray(out)[:k]
+            return np.asarray(out, np.float64)[:k]
 
 
 def _make_waterfill(nL: int, eps: float):
@@ -233,37 +234,15 @@ def _make_waterfill(nL: int, eps: float):
 
 
 # ---------------------------------------------------------------------------
-# pallas — jax water-fill + hand-written pricing kernel
+# pallas — the jax steps under the pallas name
 # ---------------------------------------------------------------------------
 class PallasBulk(JaxBulk):
-    """Water-fill inherits the jax rounds (a data-dependent while_loop
-    has no natural grid); the batch pricing step — the §17.4 re-pricing
-    of every in-flight transfer at a drain boundary — runs as a Pallas
-    gather-min kernel."""
+    """Water-fill is a data-dependent while_loop with no natural grid,
+    and pricing is a gather ``share[links]`` followed by a min that XLA
+    fuses; Mosaic has no general gather. Both steps therefore run as
+    JaxBulk's XLA, so ``pallas`` runs end to end behind one name."""
 
     name = "pallas"
-
-    def _price_core(self):
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        from repro.accel.pallas_backend import INTERPRET
-
-        def kernel(share_ref, links_ref, valid_ref, out_ref):
-            L = links_ref[...]
-            ok = valid_ref[...]
-            per = jnp.where(ok, share_ref[...][L], jnp.inf)
-            out_ref[...] = jnp.maximum(per.min(axis=1), 1.0)
-
-        def price(share, L, V):
-            import jax
-            cap = L.shape[0]
-            fn = pl.pallas_call(
-                kernel,
-                out_shape=jax.ShapeDtypeStruct((cap,), share.dtype),
-                interpret=INTERPRET)
-            return fn(share, L, V)
-        return price
 
 
 def get_bulk_backend(spec: Union[str, BulkBackend, None]) -> BulkBackend:

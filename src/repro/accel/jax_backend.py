@@ -1,9 +1,10 @@
 """JAX assessment backend: jit-compiled kernels over device-resident
 copies of the §11 columns (DESIGN.md §13.2–§13.3).
 
-Bit-exactness strategy (§13.3): every kernel runs in float64 under a
-scoped ``enable_x64`` and replicates the numpy reference *accumulation
-order* —
+Precision follows the platform (§13.3). On the CPU every kernel traces
+and runs under a scoped ``jax.enable_x64(True)`` in float64 and
+replicates the numpy reference *accumulation order*, so results are bit
+for bit the reference's:
 
 - rows are visited in the canonical (§11.3) order: the padded ``order``
   export is gathered first, and every segmented sum is an XLA scatter-add
@@ -12,9 +13,10 @@ order* —
 - small fixed axes (the k-wide neighborhoods) are summed by *unrolled*
   sequential adds — ``jnp.sum`` may re-associate, ``np.nansum`` does not
   for k < 128;
-- order-statistic math (LATE's percentile) mirrors ``np.percentile``'s
+- order statistics (LATE's percentile) are found by bisection over the
+  bit patterns of non-negative floats, then mirror ``np.percentile``'s
   linear-interpolation formula term for term;
-- order-insensitive reductions (max, any) need no special care;
+- order-insensitive reductions (max, min, any) need no special care;
 - ``a ± b·c`` chains are guarded against LLVM's FMA contraction (which
   skips the product's rounding step) by multiplying the product with a
   runtime-opaque ``one``: even if the compiler contracts, ``fma(x, 1, c)``
@@ -22,27 +24,83 @@ order* —
   (e.g. the reduce shuffle fraction) are shipped as opaque scalars too,
   so the HLO simplifier cannot re-fold the guard away.
 
+A TPU has no native float64, so there the same kernels run in float32
+(and int32), decisions are held to the §13.3 tolerance instead of bit
+equality, and nothing sorts: the TPU compiler takes minutes to build a
+sort at 64k rows. On every platform times reach the device relative to
+the tick's ``now`` (the host subtracts in float64), so float32 never
+differences two large simulated times; on the CPU that subtraction is an
+exact negation of the reference's ``now - t``.
+
 Shapes are padded by :class:`repro.core.arrays.DeviceColumns` (grow by
 doubling), so a jit specialization retraces only when the simulation
 outgrows its row/job capacity, never per tick.
 
 The traced cores (``spatial_core`` etc.) are shared: the pallas backend
-swaps the hot reductions for hand-written kernels, and the batched sweep
-(:mod:`repro.accel.sweep`) ``vmap``s :func:`assess_summary_core` across
-fault scenarios.
+swaps the segmented reductions for hand-written kernels and reuses the
+``*_pick`` tails, and the batched sweep (:mod:`repro.accel.sweep`)
+``vmap``s :func:`assess_summary_core` across fault scenarios.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.accel.base import TMARK, TPROG, AssessmentBackend
 from repro.core.arrays import SHUFFLE_FRACTION, ArraySnapshot, DeviceColumns
+
+
+# ---------------------------------------------------------------------------
+# Platform regime (§13.3): one predicate, read when a call is made
+# ---------------------------------------------------------------------------
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU: kernels then run
+    compiled, in float32. Elsewhere they run in float64, Pallas kernels
+    in interpret mode."""
+    return jax.default_backend() == "tpu"
+
+
+def precision():
+    """Scoped dtype regime for one backend call: float64 off the TPU,
+    float32 on it. Nothing in the process-global default changes."""
+    return jax.enable_x64(not on_tpu())
+
+
+# Columns holding absolute simulated times: shipped as ``t - now``.
+_TIME_COLS = ("start", "last_sync", "node_hb")
+
+
+def to_device(host: Dict[str, object], now: float) -> Dict[str, object]:
+    """Host mirror (:meth:`DeviceColumns.refresh`, optionally stacked on
+    a leading scenario axis) → device arrays in the platform's precision.
+    Call inside :func:`precision`. Times become relative to ``now``; the
+    kernels then evaluate at ``now = 0``."""
+    fdt = np.float64 if not on_tpu() else np.float32
+    dev: Dict[str, object] = {}
+    for k, v in host.items():
+        v = np.asarray(v)
+        if k in _TIME_COLS:
+            v = v - now
+        if v.dtype.kind == "f":
+            v = v.astype(fdt)
+        elif v.dtype.kind in "iu":
+            v = v.astype(np.int32)
+        dev[k] = jnp.asarray(v)
+    # Opaque scalars: anti-FMA guard + the shuffle fraction (shipped as
+    # data so the simplifier cannot re-fold, §13.3).
+    for k, val in (("one", 1.0), ("sf", SHUFFLE_FRACTION)):
+        if k not in dev:
+            dev[k] = jnp.asarray(fdt(val))
+    return dev
+
+
+def scalar(x):
+    """A host float as a device scalar of the platform's float type."""
+    return jnp.asarray(x, jnp.float32 if on_tpu() else jnp.float64)
 
 
 # ---------------------------------------------------------------------------
@@ -63,10 +121,12 @@ def prep(cols, now):
 
     Returns a dict of (cap,) arrays in canonical row order; ``posv``
     masks live positions, ``tseg`` is the global task-segment id (task
-    segments are contiguous in canonical order)."""
+    segments are contiguous in canonical order). Times are relative:
+    ``now`` is the tick's zero."""
     order = cols["order"]
     cap = order.shape[0]
-    pos = jnp.arange(cap, dtype=jnp.int64)
+    fdt = cols["one"].dtype
+    pos = jnp.arange(cap, dtype=order.dtype)
     posv = pos < cols["n_rows"]
 
     def g(name):
@@ -87,23 +147,21 @@ def prep(cols, now):
         (now - g("last_sync")) * cols["node_speed"][node])) * one
     wd = jnp.minimum(wd, work_total)
     comp = wd / work_total
-    # int/int: numpy promotes to f64, jax to f32 — cast first (§13.3).
-    shuffle = g("fetched").astype(jnp.float64) / g("deps").astype(jnp.float64)
+    # int/int: numpy promotes to float, jax keeps int — cast first.
+    shuffle = g("fetched").astype(fdt) / g("deps").astype(fdt)
     prog = jnp.where(kind == 0, comp,
                      (sf * shuffle) * one + ((one - sf) * comp) * one)
     jl = cols["job_local"][g("job")]
     jls = jnp.where(jl >= 0, jl, 0)
-    torder = g("skey") >> 20
-    prev_t = jnp.concatenate([torder[:1] - 1, torder[:-1]])
-    tseg = jnp.cumsum(torder != prev_t).astype(jnp.int64) - 1
     return {
         "cap": cap, "pos": pos, "posv": posv, "order": order,
         "a_state": a_state, "t_state": t_state, "kind": kind,
         "node": node, "spec": g("spec"), "start": start, "active": active,
-        "prog": prog, "jl": jl, "jls": jls, "tseg": tseg,
+        "prog": prog, "jl": jl, "jls": jls, "tseg": cols["tseg"],
         "mark": g(TMARK) if TMARK in cols else None,
         "tprog": g(TPROG) if TPROG in cols else None,
         "running": active & (a_state == 0) & (t_state == 1),
+        "one": one,
     }
 
 
@@ -111,7 +169,8 @@ def seg_sum(mask, seg, vals, nb):
     """Masked scatter-add into ``nb`` buckets (+1 dump), updates applied
     in operand (canonical) order — bit-equal to np.bincount (§13.3)."""
     idx = jnp.where(mask, seg, nb)
-    return jnp.zeros(nb + 1).at[idx].add(jnp.where(mask, vals, 0.0))[:nb]
+    return jnp.zeros(nb + 1, vals.dtype).at[idx].add(
+        jnp.where(mask, vals, 0))[:nb]
 
 
 def seg_sum2(mask, seg, vals_a, vals_b, nb):
@@ -121,18 +180,31 @@ def seg_sum2(mask, seg, vals_a, vals_b, nb):
     idx = jnp.where(mask, seg, nb)
     upd = jnp.stack([jnp.where(mask, vals_a, 0.0),
                      jnp.where(mask, vals_b, 0.0)], axis=-1)
-    acc = jnp.zeros((nb + 1, 2)).at[idx].add(upd)[:nb]
+    acc = jnp.zeros((nb + 1, 2), vals_a.dtype).at[idx].add(upd)[:nb]
     return acc[:, 0], acc[:, 1]
 
 
 def seg_max(mask, seg, vals, nb, init):
     idx = jnp.where(mask, seg, nb)
-    return jnp.full(nb + 1, init).at[idx].max(
+    return jnp.full(nb + 1, init, vals.dtype).at[idx].max(
+        jnp.where(mask, vals, init))[:nb]
+
+
+def seg_min(mask, seg, vals, nb, init):
+    idx = jnp.where(mask, seg, nb)
+    return jnp.full(nb + 1, init, vals.dtype).at[idx].min(
         jnp.where(mask, vals, init))[:nb]
 
 
 def seg_any(mask, seg, nb):
-    return seg_sum(mask, seg, jnp.ones(mask.shape), nb) > 0
+    return seg_max(mask, seg, mask.astype(jnp.int32), nb, 0) > 0
+
+
+def job_count(mask, key, jcap):
+    """(jcap,) count of ``mask`` entries per job key — a dense compare
+    matrix; integer sums are exact under any association."""
+    jrow = jnp.arange(jcap, dtype=key.dtype)[:, None]
+    return (mask[None, :] & (key[None, :] == jrow)).sum(axis=1)
 
 
 def spatial_mask(P, nh):
@@ -156,7 +228,7 @@ def percentile_indexes(m, q, cap, one):
     v = ((m - 1) * (q / 100.0)) * one
     lo = jnp.floor(v)
     gamma = v - lo
-    loi = jnp.clip(lo.astype(jnp.int64), 0, cap - 1)
+    loi = jnp.clip(lo.astype(jnp.int32), 0, cap - 1)
     hii = jnp.clip(loi + 1, 0, jnp.maximum(m - 1, 0))
     return loi, hii, gamma
 
@@ -168,33 +240,136 @@ def percentile_lerp(a, b, gamma, one):
                      a + (diff * gamma) * one)
 
 
-def np_percentile_sorted(srt, m, q, one):
-    """``np.percentile(x, q)`` given ``srt`` = sorted x padded with +inf
-    and ``m`` live entries."""
-    loi, hii, gamma = percentile_indexes(m, q, srt.shape[-1], one)
-    a = jnp.take_along_axis(srt, loi[..., None], axis=-1)[..., 0]
-    b = jnp.take_along_axis(srt, hii[..., None], axis=-1)[..., 0]
-    return percentile_lerp(a, b, gamma, one)
+def order_stat(member, vals, ks):
+    """Exact order statistics without a sort: ``out[..., j]`` is the
+    ``ks[..., j]``-th smallest (0-based) of ``vals`` over the entries
+    ``member[j]`` selects, ``inf`` where there are too few. Non-negative
+    floats order like their bit patterns, so a bisection over the bit
+    range lands on the exact value. It stops as soon as every member
+    left in the interval has one value — a few steps for well-separated
+    samples, at most 31 in float32 and 63 in float64. ``member`` is
+    (J, cap), ``ks`` is (..., J)."""
+    fdt = vals.dtype
+    idt = jnp.int64 if fdt == jnp.float64 else jnp.int32
+    bits = jax.lax.bitcast_convert_type(jnp.where(vals > 0, vals, 0.0), idt)
+    top = jax.lax.bitcast_convert_type(jnp.asarray(jnp.inf, fdt), idt)
+
+    def cond(lohi):
+        lo, hi = lohi
+        return jnp.any(hi - lo > 1)
+
+    def body(lohi):
+        # Invariant: the answer, if it exists, lies in (lo, hi].
+        lo, hi = lohi
+        mid = lo + (hi - lo) // 2
+        ge = (member & (bits <= mid[..., None])).sum(axis=-1) > ks
+        lo = jnp.where(ge, lo, mid)
+        hi = jnp.where(ge, mid, hi)
+        inside = member & (bits > lo[..., None]) & (bits <= hi[..., None])
+        vmin = jnp.where(inside, bits, top).min(axis=-1)
+        vmax = jnp.where(inside, bits, -1).max(axis=-1)
+        # One value left in the interval: it is the answer.
+        hi = jnp.where(vmin == vmax, vmin, hi)
+        return jnp.where(vmin == vmax, hi - 1, lo), hi
+
+    # Start from the members' own range [min, max]; where there is no
+    # answer, from the empty (inf − 1, inf].
+    exists = ks < member.sum(axis=-1)
+    lo = jnp.where(exists, jnp.where(member, bits, top).min(axis=-1) - 1,
+                   top - 1)
+    hi = jnp.where(exists, jnp.where(member, bits, -1).max(axis=-1), top)
+    _, hi = jax.lax.while_loop(cond, body, (lo, hi))
+    return jax.lax.bitcast_convert_type(hi, fdt)
+
+
+def member_percentile(member, vals, q, one):
+    """``np.percentile(vals[member[j]], q)`` for every row ``j`` of the
+    (J, cap) ``member`` mask (non-negative ``vals``), and the member
+    counts."""
+    m = member.sum(axis=1)
+    loi, hii, gamma = percentile_indexes(m, q, vals.shape[-1], one)
+    ab = order_stat(member, vals, jnp.stack([loi, hii]))
+    return percentile_lerp(ab[0], ab[1], gamma, one), m
 
 
 # ---------------------------------------------------------------------------
-# Cores (traced; shared by jit entry points, the pallas backend and the
-# batched sweep)
+# Pick tails: per-bucket reductions in, verdicts out. Shared by the XLA
+# cores below and the Pallas kernels, which compute the same reductions.
+# ---------------------------------------------------------------------------
+def spatial_pick(sums, counts, nh, jcap):
+    """(jcap, n) Eq. 1 hits from per-(job, phase, node) ρ sums/counts."""
+    n = nh.shape[0]
+    P = jnp.where(counts > 0, sums / jnp.maximum(counts, 1.0),
+                  jnp.nan).reshape(jcap * 2, n)
+    return spatial_mask(P, nh).reshape(jcap, 2, n).any(axis=1)
+
+
+def temporal_pick(p, zn, zp, cnt, samp, init, prevk, jcap, n):
+    """ζ sums → (zeta_now, zeta_prev) + this sample's scratch marks."""
+    zeta_now = jnp.where(cnt > 0, zn, jnp.nan).reshape(jcap, n)
+    zeta_prev = jnp.where(cnt > 0, zp, jnp.nan).reshape(jcap, n)
+    m = p["running"]
+    wmask = (m & samp[p["jls"]]) | (m & init[p["jls"]])
+    newk = jnp.where(samp, prevk + 1, 0)
+    newmark = jnp.where(wmask, newk[p["jls"]], p["mark"])
+    newtprog = jnp.where(wmask, p["prog"], p["tprog"])
+    return zeta_now, zeta_prev, wmask, newmark, newtprog
+
+
+def temporal_alive(p, samp, prevk):
+    """Running rows of sampled jobs alive at both Eq. 2 samples."""
+    return p["running"] & samp[p["jls"]] & (p["mark"] == prevk[p["jls"]])
+
+
+def late_pick(p, best_prog, bpos, has_spec, now, min_runtime, q, jcap):
+    """(jcap,) LATE victim rows (-1 = no victim) from the per-task best
+    running attempt (``bpos`` = its canonical position, ``cap`` if the
+    task has none) — mirror of ``NumpyBackend._late_victim``."""
+    cap = p["cap"]
+    one = p["one"]
+    rep = bpos < cap
+    bp = jnp.minimum(bpos, cap - 1)
+    best_prog = jnp.where(rep, best_prog, 0.0)
+    best_start = p["start"][bp]
+    sjl = p["jls"][bp]
+    ok = rep & ~has_spec & (now - best_start >= min_runtime)
+    rho = best_prog / jnp.maximum(now - best_start, 1e-9)
+    est = (1.0 - best_prog) / jnp.maximum(rho, 1e-9)
+    # Per-job percentile over the ok candidates: exact order statistics
+    # + numpy's linear interpolation.
+    jrow = jnp.arange(jcap, dtype=sjl.dtype)[:, None]
+    member = ok[None, :] & (sjl[None, :] == jrow)          # (jcap, cap)
+    thresh, msel = member_percentile(member, rho, q, one)
+    slow = ok & (rho < thresh[sjl])
+    # Victim = max est_remaining among slow, lowest task on ties.
+    top = seg_max(slow, sjl, est, jcap, -jnp.inf)
+    vt = seg_min(slow & (est == top[sjl]), sjl,
+                 jnp.arange(cap, dtype=bp.dtype), jcap, cap)
+    vict_row = p["order"][bp[jnp.minimum(vt, cap - 1)]]
+    good = (job_count(p["running"], p["jls"], jcap) >= 2) & (msel >= 2) \
+        & (vt < cap)
+    return jnp.where(good, vict_row, -1)
+
+
+def winning_pick(has_spec, has_orig, hi, lo, wjl, win_factor, jcap):
+    """(jcap,) collective 'speculation is winning' verdicts from the
+    per-task spec/orig presence, max rates and job (-1 = no attempt)."""
+    win_seg = has_spec & (~has_orig | (hi > lo * win_factor))
+    return seg_any(win_seg & (wjl >= 0), jnp.where(wjl >= 0, wjl, 0), jcap)
+
+
+# ---------------------------------------------------------------------------
+# Cores (traced; shared by jit entry points and the batched sweep)
 # ---------------------------------------------------------------------------
 def spatial_core(cols, nh, now, jcap):
     """(jcap, n_nodes) Eq. 1 hits (both phases merged)."""
     p = prep(cols, now)
     n = nh.shape[0]
-    rt = jnp.maximum(now - p["start"], 1e-9)
-    rho = p["prog"] / rt
+    rho = p["prog"] / jnp.maximum(now - p["start"], 1e-9)
     seg = (p["jls"] * 2 + p["kind"]) * n + p["node"]
-    nb = jcap * 2 * n
-    m = p["running"]
-    sums, counts = seg_sum2(m, seg, rho, jnp.ones(rho.shape), nb)
-    P = jnp.where(counts > 0, sums / jnp.maximum(counts, 1.0),
-                  jnp.nan).reshape(jcap * 2, n)
-    fired = spatial_mask(P, nh)
-    return fired.reshape(jcap, 2, n).any(axis=1)
+    sums, counts = seg_sum2(p["running"], seg, rho,
+                            jnp.ones_like(rho), jcap * 2 * n)
+    return spatial_pick(sums, counts, nh, jcap)
 
 
 def temporal_core(cols, now, samp, init, prevk, n_nodes):
@@ -203,22 +378,13 @@ def temporal_core(cols, now, samp, init, prevk, n_nodes):
     p = prep(cols, now)
     jcap = samp.shape[0]
     n = n_nodes
-    m = p["running"]
-    samp_r = m & samp[p["jls"]]
-    init_r = m & init[p["jls"]]
-    alive = samp_r & (p["mark"] == prevk[p["jls"]])
+    alive = temporal_alive(p, samp, prevk)
     seg = p["jls"] * n + p["node"]
     nb = jcap * n
     zn = seg_sum(alive, seg, p["prog"], nb)
     zp = seg_sum(alive, seg, p["tprog"], nb)
-    cnt = seg_sum(alive, seg, jnp.ones(p["prog"].shape), nb)
-    zeta_now = jnp.where(cnt > 0, zn, jnp.nan).reshape(jcap, n)
-    zeta_prev = jnp.where(cnt > 0, zp, jnp.nan).reshape(jcap, n)
-    wmask = samp_r | init_r
-    newk = jnp.where(samp, prevk + 1, 0)
-    newmark = jnp.where(wmask, newk[p["jls"]], p["mark"])
-    newtprog = jnp.where(wmask, p["prog"], p["tprog"])
-    return zeta_now, zeta_prev, wmask, newmark, newtprog
+    cnt = seg_sum(alive, seg, jnp.ones_like(p["prog"]), nb)
+    return temporal_pick(p, zn, zp, cnt, samp, init, prevk, jcap, n)
 
 
 def failure_core(now, node_hb, node_marked, declared, thresholds,
@@ -229,66 +395,19 @@ def failure_core(now, node_hb, node_marked, declared, thresholds,
     return resp, cand
 
 
-def _block_starts(keys, jcap):
-    """Per-job (count, exclusive-start) over a job-keyed sorted array
-    (dump entries carry key == jcap). Integer sums are exact under any
-    association, so the (jcap, cap) count matrix is bit-safe."""
-    jrow = jnp.arange(jcap, dtype=keys.dtype)[:, None]
-    cnt = (keys[None, :] == jrow).sum(axis=1)
-    return cnt, jnp.cumsum(cnt) - cnt
-
-
 def late_core(cols, now, min_runtime, q, jcap):
-    """(jcap,) LATE victim rows (-1 = no victim).
-
-    Selection runs on multi-key sorts instead of per-bucket scatters:
-    grouping keys first, value keys second, the canonical position as
-    the final tie-break — so 'max ζ, first-wins' and 'max estimate,
-    lowest segment' come out of block heads exactly as the reference
-    picks them, and the per-job percentile reads order statistics from
-    a job-keyed sorted run (§13.3: order statistics and first-of-max
-    picks are order-insensitive, hence bit-exact)."""
+    """(jcap,) LATE victim rows (-1 = no victim). Per-task best running
+    attempt: max ζ, first canonical position on ties (= Python max()'s
+    first-wins) — a scatter-max, then a scatter-min over the rows that
+    reach it."""
     p = prep(cols, now)
     cap = p["cap"]
     m = p["running"]
-    big = jnp.int64(cap)
-    k1 = jnp.where(m, p["tseg"], big)
-    # Per-task best running attempt: max ζ first, canonical position as
-    # the tie-break (= Python max()'s first-wins).
-    k1s, _negp, bpos, best_prog, best_start, sjl = jax.lax.sort(
-        (k1, -p["prog"], p["pos"], p["prog"], p["start"], p["jls"]),
-        num_keys=3)
-    first = jnp.concatenate([jnp.ones(1, dtype=bool), k1s[1:] != k1s[:-1]])
-    rep = (k1s < big) & first                  # block head = the best row
-    # Any speculative sibling among the task's running attempts: same
-    # block structure (identical key multiset), spec-first ordering.
-    _k1s2, negspec = jax.lax.sort(
-        (k1, -p["spec"].astype(jnp.int64)), num_keys=2)
-    has_spec = negspec == -1
-    ok = rep & ~has_spec & (now - best_start >= min_runtime)
-    rho = best_prog / jnp.maximum(now - best_start, 1e-9)
-    est = (1.0 - best_prog) / jnp.maximum(rho, 1e-9)
-    # Per-job percentile over the ok candidates: job-keyed sorted run +
-    # numpy's linear interpolation on the block's order statistics.
-    kj = jnp.where(ok, sjl, jnp.int64(jcap))
-    kjs, rhos = jax.lax.sort((kj, rho), num_keys=2)
-    msel_j, starts_j = _block_starts(kjs, jcap)
-    one = cols["one"]
-    loi, hii, gamma = percentile_indexes(msel_j, q, cap, one)
-    a = rhos[jnp.clip(starts_j + loi, 0, cap - 1)]
-    b = rhos[jnp.clip(starts_j + hii, 0, cap - 1)]
-    thresh = percentile_lerp(a, b, gamma, one)
-    slow = ok & (rho < thresh[sjl])
-    # Victim = max est_remaining among slow, lowest task on ties.
-    kv = jnp.where(slow, sjl, jnp.int64(jcap))
-    kvs, _nege, _tie, vpos = jax.lax.sort((kv, -est, k1s, bpos),
-                                          num_keys=3)
-    nslow_j, starts_v = _block_starts(kvs, jcap)
-    vict_row = cols["order"][vpos[jnp.clip(starts_v, 0, cap - 1)] % cap]
-    nrows_j, _ = _block_starts(jnp.where(m, p["jls"], jnp.int64(jcap)),
-                               jcap)
-    good = (nrows_j >= 2) & (msel_j >= 2) & (nslow_j > 0)
-    return jnp.where(good, vict_row, -1)
+    tseg = p["tseg"]
+    best = seg_max(m, tseg, p["prog"], cap, -jnp.inf)
+    bpos = seg_min(m & (p["prog"] == best[tseg]), tseg, p["pos"], cap, cap)
+    has_spec = seg_any(m & p["spec"], tseg, cap)
+    return late_pick(p, best, bpos, has_spec, now, min_runtime, q, jcap)
 
 
 def winning_core(cols, now, win_factor, jcap):
@@ -302,9 +421,8 @@ def winning_core(cols, now, win_factor, jcap):
     lo = seg_max(m & ~p["spec"], tseg, rate, cap, -jnp.inf)
     has_spec = seg_any(m & p["spec"], tseg, cap)
     has_orig = seg_any(m & ~p["spec"], tseg, cap)
-    win_seg = has_spec & (~has_orig | (hi > lo * win_factor))
-    wjl = seg_max(m, tseg, p["jls"], cap, jnp.int64(-1))
-    return seg_any(win_seg & (wjl >= 0), jnp.where(wjl >= 0, wjl, 0), jcap)
+    wjl = seg_max(m, tseg, p["jls"], cap, -1)
+    return winning_pick(has_spec, has_orig, hi, lo, wjl, win_factor, jcap)
 
 
 def reap_core(cols, now):
@@ -353,13 +471,6 @@ _reap_jit = jax.jit(reap_core)
 class JaxBackend(AssessmentBackend):
     name = "jax"
 
-    # Entry points — the pallas subclass overrides the hot two.
-    _spatial_fn = staticmethod(_spatial_jit)
-    _temporal_fn = staticmethod(_temporal_jit)
-    _late_fn = staticmethod(_late_jit)
-    _winning_fn = staticmethod(_winning_jit)
-    _reap_fn = staticmethod(_reap_jit)
-
     def __init__(self) -> None:
         self._dc: Optional[DeviceColumns] = None
         self._memo: Tuple[float, Optional[tuple]] = (np.nan, None)
@@ -368,6 +479,23 @@ class JaxBackend(AssessmentBackend):
         self._win_memo = (np.nan, np.nan, None, None)
         self._nh_dev = None
         self._nh_host = None
+        self.upload_bytes = 0     # bytes of the last per-tick upload
+
+    # Entry points — the pallas subclass overrides the segmented passes.
+    def _spatial_fn(self, cols, nh, now, jcap):
+        return _spatial_jit(cols, nh, now, jcap=jcap)
+
+    def _temporal_fn(self, cols, now, samp, init, prevk, n_nodes):
+        return _temporal_jit(cols, now, samp, init, prevk, n_nodes=n_nodes)
+
+    def _late_fn(self, cols, now, min_runtime, q, jcap):
+        return _late_jit(cols, now, min_runtime, q, jcap=jcap)
+
+    def _winning_fn(self, cols, now, win_factor, jcap):
+        return _winning_jit(cols, now, win_factor, jcap=jcap)
+
+    def _reap_fn(self, cols, now):
+        return _reap_jit(cols, now)
 
     # ------------------------------------------------------------------
     def _cols(self, arr: ArraySnapshot, now: float, active) -> tuple:
@@ -382,35 +510,26 @@ class JaxBackend(AssessmentBackend):
         arr.scratch(TMARK, np.int64, -1)
         arr.scratch(TPROG, np.float64, np.nan)
         host = self._dc.refresh(active, scratch_names=(TMARK, TPROG))
-        with enable_x64():
-            dev = {}
-            for k, v in host.items():
-                if isinstance(v, np.ndarray):
-                    dev[k] = jnp.asarray(v)
-                else:
-                    dev[k] = jnp.asarray(np.int64(v))
-            # Opaque scalars: anti-FMA guard + the shuffle fraction
-            # (shipped as data so the simplifier cannot re-fold, §13.3).
-            dev["one"] = jnp.float64(1.0)
-            dev["sf"] = jnp.float64(SHUFFLE_FRACTION)
+        with precision():
+            dev = to_device(host, now)
+        self.upload_bytes = sum(int(v.nbytes) for v in dev.values())
         out = (dev, self._dc.jcap)
         self._memo = (now, out)
         return out
 
     def _nh(self, neighborhoods: np.ndarray):
         if self._nh_host is not neighborhoods:
-            with enable_x64():
-                self._nh_dev = jnp.asarray(
-                    np.asarray(neighborhoods, dtype=np.int64))
+            self._nh_dev = jnp.asarray(
+                np.asarray(neighborhoods, dtype=np.int32))
             self._nh_host = neighborhoods
         return self._nh_dev
 
     # ------------------------------------------------------------------
     def spatial_hits(self, arr, now, active, neighborhoods):
         cols, jcap = self._cols(arr, now, active)
-        with enable_x64():
+        with precision():
             hits = self._spatial_fn(cols, self._nh(neighborhoods),
-                                    jnp.float64(now), jcap=jcap)
+                                    scalar(0.0), jcap)
         return np.asarray(hits)[:len(active)]
 
     def temporal_zeta(self, arr, now, active, samp_flag, init_flag, prevk):
@@ -421,12 +540,12 @@ class JaxBackend(AssessmentBackend):
         sampd[:J] = samp_flag
         initd = np.zeros(jcap, dtype=bool)
         initd[:J] = init_flag
-        prevkd = np.full(jcap, -2, dtype=np.int64)
+        prevkd = np.full(jcap, -2, dtype=np.int32)
         prevkd[:J] = prevk
-        with enable_x64():
+        with precision():
             zn, zp, wmask, newmark, newtprog = self._temporal_fn(
-                cols, jnp.float64(now), jnp.asarray(sampd),
-                jnp.asarray(initd), jnp.asarray(prevkd), n_nodes=n)
+                cols, scalar(0.0), jnp.asarray(sampd),
+                jnp.asarray(initd), jnp.asarray(prevkd), n)
         # Scratch write-back: the device computed this sample's marks in
         # canonical order; apply them to the host columns.
         n_rows = arr.n
@@ -437,26 +556,26 @@ class JaxBackend(AssessmentBackend):
                 np.asarray(newmark)[:n_rows][w]
             arr.scratch(TPROG, np.float64, np.nan)[rows] = \
                 np.asarray(newtprog)[:n_rows][w]
-        return np.asarray(zn)[:J], np.asarray(zp)[:J]
+        return (np.asarray(zn, dtype=np.float64)[:J],
+                np.asarray(zp, dtype=np.float64)[:J])
 
     def failure_masks(self, now, node_hb, node_marked, declared,
                       thresholds, responsive_window):
-        with enable_x64():
+        with precision():
+            f = scalar(0.0).dtype
             resp, cand = _failure_jit(
-                jnp.float64(now), jnp.asarray(node_hb),
+                scalar(0.0), jnp.asarray(np.asarray(node_hb) - now, f),
                 jnp.asarray(node_marked), jnp.asarray(declared),
-                jnp.asarray(thresholds), jnp.float64(responsive_window))
+                jnp.asarray(thresholds, f), scalar(responsive_window))
         return np.asarray(resp), np.asarray(cand)
 
     def late_victims(self, arr, now, active, eligible, min_runtime,
                      slow_task_percentile):
         cols, jcap = self._cols(arr, now, active)
-        with enable_x64():
-            victims = self._late_fn(cols, jnp.float64(now),
-                                    jnp.float64(min_runtime),
-                                    jnp.float64(slow_task_percentile),
-                                    jcap=jcap)
-        return np.asarray(victims)[:len(active)]
+        with precision():
+            victims = self._late_fn(cols, scalar(0.0), scalar(min_runtime),
+                                    scalar(slow_task_percentile), jcap)
+        return np.asarray(victims, dtype=np.int64)[:len(active)]
 
     def winning(self, arr, now, job_idx, win_factor):
         active = arr.active_jobs()
@@ -465,10 +584,9 @@ class JaxBackend(AssessmentBackend):
             win = self._win_memo[2]
         else:
             cols, jcap = self._cols(arr, now, active)
-            with enable_x64():
+            with precision():
                 win = np.asarray(self._winning_fn(
-                    cols, jnp.float64(now), jnp.float64(win_factor),
-                    jcap=jcap))
+                    cols, scalar(0.0), scalar(win_factor), jcap))
             self._win_memo = (now, win_factor, win, arr)
         jl = arr.job_local_map(active)
         pos = jl[job_idx] if 0 <= job_idx < len(jl) else -1
@@ -479,8 +597,8 @@ class JaxBackend(AssessmentBackend):
     def reap_rows(self, arr, now):
         active = arr.active_jobs()
         cols, _jcap = self._cols(arr, now, active)
-        with enable_x64():
-            reap = self._reap_fn(cols, jnp.float64(now))
+        with precision():
+            reap = self._reap_fn(cols, scalar(0.0))
         mask = np.asarray(reap)[:arr.n]
         return arr.order()[mask]
 
@@ -489,14 +607,22 @@ __all__ = [
     "JaxBackend",
     "assess_summary_core",
     "late_core",
-    "np_percentile_sorted",
+    "late_pick",
+    "member_percentile",
+    "on_tpu",
+    "order_stat",
     "ordered_sum",
     "percentile_indexes",
     "percentile_lerp",
+    "precision",
     "prep",
     "reap_core",
     "spatial_core",
     "spatial_mask",
+    "spatial_pick",
     "temporal_core",
+    "temporal_pick",
+    "to_device",
     "winning_core",
+    "winning_pick",
 ]

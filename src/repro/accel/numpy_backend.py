@@ -41,23 +41,32 @@ class NumpyBackend(AssessmentBackend):
         return data
 
     # -- Eq. 1 ----------------------------------------------------------
-    def spatial_hits(self, arr, now, active, neighborhoods):
+    def spatial_rates(self, arr, now, active) -> Optional[np.ndarray]:
+        """(J·2, n) mean progress rate P per (job, phase, node), NaN
+        where a node runs nothing; None when no attempt runs."""
         rows, prog, jl = self._tick(arr, now, active)
+        if not len(rows):
+            return None
         n = len(arr.node_ids)
         J = len(active)
-        fired = np.zeros((J * 2, n), dtype=bool)
-        if len(rows):
-            rt = np.maximum(now - arr.start[rows], 1e-9)
-            rho = prog / rt
-            seg = (jl * 2 + arr.kind[rows]) * n + arr.node[rows]
-            # bincount accumulates sequentially in input order — the same
-            # partial-sum order as the reference append loops.
-            sums = np.bincount(seg, weights=rho, minlength=J * 2 * n)
-            counts = np.bincount(seg, minlength=J * 2 * n).astype(float)
-            with np.errstate(invalid="ignore"):
-                P = np.where(counts > 0, sums / np.maximum(counts, 1.0),
-                             np.nan).reshape(J * 2, n)
-            fired = M.spatial_slow_mask_batch_np(P, neighborhoods)
+        rt = np.maximum(now - arr.start[rows], 1e-9)
+        rho = prog / rt
+        seg = (jl * 2 + arr.kind[rows]) * n + arr.node[rows]
+        # bincount accumulates sequentially in input order — the same
+        # partial-sum order as the reference append loops.
+        sums = np.bincount(seg, weights=rho, minlength=J * 2 * n)
+        counts = np.bincount(seg, minlength=J * 2 * n).astype(float)
+        with np.errstate(invalid="ignore"):
+            return np.where(counts > 0, sums / np.maximum(counts, 1.0),
+                            np.nan).reshape(J * 2, n)
+
+    def spatial_hits(self, arr, now, active, neighborhoods):
+        n = len(arr.node_ids)
+        J = len(active)
+        P = self.spatial_rates(arr, now, active)
+        if P is None:
+            return np.zeros((J, n), dtype=bool)
+        fired = M.spatial_slow_mask_batch_np(P, neighborhoods)
         return fired.reshape(J, 2, n).any(axis=1)
 
     # -- Eq. 2–3 --------------------------------------------------------
@@ -107,14 +116,21 @@ class NumpyBackend(AssessmentBackend):
                     arr, now, jidx, min_runtime, slow_task_percentile)
         return victims
 
-    def _late_victim(self, arr, now, job_idx, min_runtime,
-                     slow_task_percentile) -> int:
+    @staticmethod
+    def late_scores(arr, now, job_idx, min_runtime, slow_task_percentile
+                    ) -> Optional[dict]:
+        """The numbers one job's LATE pick compares, or None when the
+        job runs fewer than two attempts: per row ``prog``; per task
+        ``segmax``/``inv``/``starts`` and the ``best`` row-position;
+        its ``elapsed`` time; and over the candidates ``sel`` their
+        ``rho``, ``est`` and the percentile ``thresh`` (absent with
+        fewer than two candidates)."""
         m = arr.active[:arr.n] & (arr.job[:arr.n] == job_idx) \
             & (arr.a_state[:arr.n] == A_RUNNING) \
             & (arr.t_state[:arr.n] == T_RUNNING)
         rows = arr.rows_where(m)
         if len(rows) < 2:
-            return -1
+            return None
         # Segment per task (rows are canonical, so task segments are
         # contiguous); per task pick the max-progress running attempt,
         # first-wins on ties — exactly Python's max() over attempt order.
@@ -127,33 +143,43 @@ class NumpyBackend(AssessmentBackend):
         cand = np.flatnonzero(prog == segmax[inv])
         _, first = np.unique(inv[cand], return_index=True)
         best = cand[first]                      # one row-position per task
-        ok = ~has_spec & (now - arr.start[rows[best]] >= min_runtime)
-        sel = best[ok]
-        if len(sel) < 2:
+        elapsed = now - arr.start[rows[best]]
+        sel = best[~has_spec & (elapsed >= min_runtime)]
+        sc = {"rows": rows, "prog": prog, "starts": starts, "inv": inv,
+              "segmax": segmax, "best": best, "elapsed": elapsed,
+              "sel": sel}
+        if len(sel) >= 2:
+            p = prog[sel]
+            sc["rho"] = p / np.maximum(now - arr.start[rows[sel]], 1e-9)
+            sc["est"] = (1.0 - p) / np.maximum(sc["rho"], 1e-9)
+            sc["thresh"] = np.percentile(sc["rho"], slow_task_percentile)
+        return sc
+
+    def _late_victim(self, arr, now, job_idx, min_runtime,
+                     slow_task_percentile) -> int:
+        sc = self.late_scores(arr, now, job_idx, min_runtime,
+                              slow_task_percentile)
+        if sc is None or "thresh" not in sc:
             # LATE needs variation among tasks to rank stragglers — with
             # zero or one candidate there is nothing to compare against
             # (the scope-limited myopia, faithfully reproduced).
             return -1
-        p = prog[sel]
-        rho = p / np.maximum(now - arr.start[rows[sel]], 1e-9)
-        est_remaining = (1.0 - p) / np.maximum(rho, 1e-9)
-        thresh = np.percentile(rho, slow_task_percentile)
-        slow = np.flatnonzero(rho < thresh)
+        slow = np.flatnonzero(sc["rho"] < sc["thresh"])
         if not len(slow):
             return -1
-        return int(rows[sel][slow[np.argmax(est_remaining[slow])]])
+        return int(sc["rows"][sc["sel"]][slow[np.argmax(sc["est"][slow])]])
 
     # -- collective -----------------------------------------------------
-    def winning(self, arr, now, job_idx, win_factor):
-        """Per-task max progress rate of original vs speculative running
-        attempts, any task wins ⇒ ramp. Boolean-equivalent to the
-        reference scan (max is order-free and each rate is computed with
-        identical arithmetic)."""
+    @staticmethod
+    def task_rates(arr, now, job_idx) -> Optional[tuple]:
+        """Per task of the job's running attempts: (max speculative rate,
+        max original rate, has speculative, has original); None when no
+        speculative attempt runs."""
         m = arr.active[:arr.n] & (arr.job[:arr.n] == job_idx) \
             & (arr.a_state[:arr.n] == A_RUNNING)
         rows = arr.rows_where(m)
         if not len(rows) or not arr.spec[rows].any():
-            return False
+            return None
         rate = arr.progress_at(now, rows) \
             / np.maximum(now - arr.start[rows], 1e-9)
         starts, inv = arr.task_segments(arr.skey[rows] >> 20)
@@ -165,6 +191,17 @@ class NumpyBackend(AssessmentBackend):
         np.maximum.at(lo, inv[~sp], rate[~sp])
         has_spec = np.bincount(inv, weights=sp, minlength=k) > 0
         has_orig = np.bincount(inv, weights=~sp, minlength=k) > 0
+        return hi, lo, has_spec, has_orig
+
+    def winning(self, arr, now, job_idx, win_factor):
+        """Per-task max progress rate of original vs speculative running
+        attempts, any task wins ⇒ ramp. Boolean-equivalent to the
+        reference scan (max is order-free and each rate is computed with
+        identical arithmetic)."""
+        r = self.task_rates(arr, now, job_idx)
+        if r is None:
+            return False
+        hi, lo, has_spec, has_orig = r
         win = has_spec & (~has_orig | (hi > lo * win_factor))
         return bool(win.any())
 

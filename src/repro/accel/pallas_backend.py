@@ -1,359 +1,268 @@
-"""Pallas assessment backend: hand-written kernels for the two hottest
-assessment reductions (DESIGN.md §13.2).
+"""Pallas assessment backend: hand-written kernels for the segmented
+reductions of an assessment tick (DESIGN.md §13.2).
 
 Kernel layout
 -------------
-- **Glance kernel** (grid = one program per padded job): the Eq. 1
-  spatial pass — per-(phase, node) ρ sums/counts accumulated by a
-  sequential row scan in canonical order (bit-equal to ``np.bincount``),
-  then the neighborhood mean−σ test with unrolled k-sums — and, in the
-  temporal variant, the Eq. 2–3 ζ accumulation over attempts alive at
-  both samples.
-- **LATE/collective kernel** (grid = one program per padded job): the
-  per-task segment scan (best running attempt first-wins, speculative
-  flags, original-vs-speculative max rates), LATE's percentile rank +
-  victim pick, and the collective winning verdict. A gridless sibling
-  scans sibling-reap candidates.
+Every pass is one kernel shape, the **bucket pass**: per canonical row
+``r`` a segment id ``seg[r]`` (``-1`` = row unused) and a few scalars;
+per bucket ``b`` a few accumulators. The grid is (bucket tiles, row
+blocks). Row blocks stream through scalar memory (SMEM), 1024 rows at
+a time; the accumulators of one tile stay in vector memory as whole
+``(8·v, 128)`` float tiles. Each row is read as scalars and folded into
+every bucket of the tile at once by compare-and-select, so one bucket
+sees its rows strictly in canonical order — the sequential accumulation
+of ``np.bincount`` (bit-exact in float64 interpret mode, §13.3) without
+a single scalar store to vector memory, which Mosaic refuses. A row
+block whose segment ids cannot reach the tile is skipped, read from
+scalar-prefetched per-block bounds: contiguous keys (task segments)
+touch one or two blocks per tile.
 
-Elementwise projections (ζ progress, rates, masks) are prepared by the
-shared :func:`repro.accel.jax_backend.prep` — the kernels own the
-*reductions*, which is where the assessment wall is (ROADMAP).
+- **Glance kernel** — Eq. 1 ρ sums/counts per (job, phase, node).
+- **Temporal kernel** — Eq. 2–3 ζ sums per (job, node) over attempts
+  alive at both samples.
+- **LATE kernel** — per task, the best running attempt (max ζ, first in
+  canonical order on ties) and whether a speculative sibling runs.
+- **Collective kernel** — per task, max speculative / original rates,
+  their presence, and the task's job.
+- **Reap kernel** — per task, whether an attempt completed.
 
-``interpret=True`` is the default so CI and laptop runs execute without
-a TPU/GPU; set ``REPRO_PALLAS_COMPILE=1`` to lower to Mosaic on real
-devices. Compiled-mode caveats (f32, in-kernel sort support) are the
-documented §13.3 exactness waivers; interpret mode is bit-exact against
-the numpy backend and gated so by tests/test_accel.py.
+Elementwise projections (ζ progress, rates, masks) come from the shared
+:func:`repro.accel.jax_backend.prep`, and the verdicts from the shared
+``*_pick`` tails; the kernels own the reductions.
+
+Interpret mode is chosen from the platform when a call is made
+(:func:`repro.accel.jax_backend.on_tpu`): compiled for Mosaic on a TPU,
+interpreted elsewhere. There is no switch.
 """
 from __future__ import annotations
 
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.accel.jax_backend import (
     JaxBackend,
-    np_percentile_sorted,
-    ordered_sum,
+    late_pick,
+    on_tpu,
     prep,
+    spatial_pick,
+    temporal_alive,
+    temporal_pick,
+    winning_pick,
 )
 
-# Interpret by default: the baked container has no TPU, and CI pins
-# JAX_PLATFORMS=cpu. Real devices opt in explicitly.
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "") in ("", "0")
+ROW_BLOCK = 1024          # rows per SMEM block
+LANES = 128
+_VREG = 8 * LANES         # buckets per (8, 128) float32 vreg
 
 
-# ---------------------------------------------------------------------------
-# Glance kernel — Eq. 1 spatial pass (+ Eq. 2–3 ζ accumulation variant)
-# ---------------------------------------------------------------------------
-def _spatial_kernel(rho_ref, node_ref, kind_ref, jls_ref, run_ref, nh_ref,
-                    fired_ref, sums_ref, counts_ref):
-    j = pl.program_id(0)
-    cap = rho_ref.shape[0]
-    n = nh_ref.shape[0]
-    sums_ref[...] = jnp.zeros((2, n), sums_ref.dtype)
-    counts_ref[...] = jnp.zeros((2, n), counts_ref.dtype)
+def bucket_pass(update, inits, seg, cols, nb, dtype, *, tile, interpret):
+    """Segmented reduction of ``cols`` over ``nb`` buckets.
 
-    def body(i, carry):
-        # Sequential scan in canonical order: per-bucket partial sums
-        # round exactly like the reference bincount (§13.3). Masked rows
-        # add 0.0 — a bitwise no-op on the (non-negative) accumulators.
-        use = (run_ref[i] == 1) & (jls_ref[i] == j)
-        ph = kind_ref[i]
-        nd = node_ref[i]
-        sums_ref[ph, nd] = sums_ref[ph, nd] + jnp.where(use, rho_ref[i], 0.0)
-        counts_ref[ph, nd] = counts_ref[ph, nd] + jnp.where(use, 1.0, 0.0)
-        return carry
+    ``seg`` is (cap,) int32 (``-1`` skips a row), ``cols`` a list of
+    (cap,) per-row scalars, ``inits`` one fill per accumulator.
+    ``update(hit, r, vals, accs) -> accs`` folds row ``r`` (canonical
+    position; ``hit`` = bucket ids equal to ``seg[r]``) into the tile's
+    accumulators. ``tile`` is the tile width in vregs. Returns one (nb,)
+    array of ``dtype`` per accumulator."""
+    cap = seg.shape[0]
+    rb = min(ROW_BLOCK, cap)
+    nblk = cap // rb
+    tb = min(tile * _VREG, pl.cdiv(nb, _VREG) * _VREG)
+    nt = pl.cdiv(nb, tb)
+    tbs = tb // LANES
+    segb = seg.reshape(nblk, rb)
+    used = segb >= 0
+    blo = jnp.where(used, segb, jnp.iinfo(jnp.int32).max).min(axis=1)
+    bhi = jnp.where(used, segb, -1).max(axis=1)
+    ncol = len(cols)
 
-    jax.lax.fori_loop(0, cap, body, 0)
-    sums = sums_ref[...]
-    counts = counts_ref[...]
-    P = jnp.where(counts > 0, sums / jnp.maximum(counts, 1.0), jnp.nan)
-    Pn = P[:, nh_ref[...]]                       # (2, n, k)
-    valid = ~jnp.isnan(Pn)
-    cnt = valid.sum(axis=2)
-    mean = ordered_sum(jnp.where(valid, Pn, 0.0)) / jnp.maximum(cnt, 1)
-    var = ordered_sum(jnp.where(valid, (Pn - mean[:, :, None]) ** 2, 0.0)) \
-        / jnp.maximum(cnt, 1)
-    std = jnp.sqrt(var)
-    ok = (cnt >= 2) & ~jnp.isnan(P)
-    fired_ref[0] = ok & (P < (mean - std))
+    def kernel(blo_ref, bhi_ref, seg_ref, *refs):
+        col_refs, acc_refs = refs[:ncol], refs[ncol:]
+        t = pl.program_id(0)
+        k = pl.program_id(1)
+        b0 = t * tb
 
+        @pl.when(k == 0)
+        def _init():
+            for ref, v in zip(acc_refs, inits):
+                ref[...] = jnp.full(ref.shape, v, ref.dtype)
 
-def _temporal_kernel(prog_ref, tprog_ref, node_ref, jls_ref, alive_ref,
-                     zn_ref, zp_ref, cnt_ref):
-    j = pl.program_id(0)
-    cap = prog_ref.shape[0]
-    n = zn_ref.shape[1]
-    zn_ref[...] = jnp.zeros((1, n), zn_ref.dtype)
-    zp_ref[...] = jnp.zeros((1, n), zp_ref.dtype)
-    cnt_ref[...] = jnp.zeros((n,), cnt_ref.dtype)
+        @pl.when((bhi_ref[k] >= b0) & (blo_ref[k] < b0 + tb))
+        def _scan():
+            bid = b0 + jax.lax.broadcasted_iota(jnp.int32, (tbs, LANES), 0) \
+                * LANES + jax.lax.broadcasted_iota(jnp.int32, (tbs, LANES), 1)
 
-    def body(i, carry):
-        use = (alive_ref[i] == 1) & (jls_ref[i] == j)
-        nd = node_ref[i]
-        zn_ref[0, nd] = zn_ref[0, nd] + jnp.where(use, prog_ref[i], 0.0)
-        zp_ref[0, nd] = zp_ref[0, nd] + jnp.where(use, tprog_ref[i], 0.0)
-        cnt_ref[nd] = cnt_ref[nd] + jnp.where(use, jnp.int32(1),
-                                              jnp.int32(0))
-        return carry
+            def row(i, accs):
+                vals = [ref[i] for ref in col_refs]
+                return tuple(update(bid == seg_ref[i], k * rb + i, vals,
+                                    list(accs)))
 
-    jax.lax.fori_loop(0, cap, body, 0)
-    have = cnt_ref[...] > 0
-    zn_ref[0] = jnp.where(have, zn_ref[0], jnp.nan)
-    zp_ref[0] = jnp.where(have, zp_ref[0], jnp.nan)
+            accs = jax.lax.fori_loop(
+                0, rb, row, tuple(ref[...] for ref in acc_refs))
+            for ref, a in zip(acc_refs, accs):
+                ref[...] = a
 
-
-# ---------------------------------------------------------------------------
-# LATE / collective kernel — per-task segment pass
-# ---------------------------------------------------------------------------
-def _late_kernel(prog_ref, start_ref, rate_ref, spec_ref,
-                 tseg_ref, jls_ref, run_ref, att_ref, order_ref, params_ref,
-                 vict_ref, win_ref,
-                 bprog_ref, bpos_ref, bstart_ref, hspec_ref,
-                 hi_ref, lo_ref, sp2_ref, org_ref):
-    j = pl.program_id(0)
-    cap = prog_ref.shape[0]
-    now = params_ref[0]
-    min_runtime = params_ref[1]
-    q = params_ref[2]
-    win_factor = params_ref[3]
-    one = params_ref[4]
-    neg = -jnp.inf
-    bprog_ref[...] = jnp.full((cap + 1,), neg, bprog_ref.dtype)
-    bpos_ref[...] = jnp.full((cap + 1,), cap, bpos_ref.dtype)
-    bstart_ref[...] = jnp.zeros((cap + 1,), bstart_ref.dtype)
-    hspec_ref[...] = jnp.zeros((cap + 1,), hspec_ref.dtype)
-    hi_ref[...] = jnp.full((cap + 1,), neg, hi_ref.dtype)
-    lo_ref[...] = jnp.full((cap + 1,), neg, lo_ref.dtype)
-    sp2_ref[...] = jnp.zeros((cap + 1,), sp2_ref.dtype)
-    org_ref[...] = jnp.zeros((cap + 1,), org_ref.dtype)
-
-    def body(i, nrows):
-        s = tseg_ref[i]
-        sp = spec_ref[i] == 1
-        # LATE candidate rows: per-task max-ζ attempt, FIRST-wins on ties
-        # (strictly-greater update in ascending canonical order).
-        is_run = (run_ref[i] == 1) & (jls_ref[i] == j)
-        sl = jnp.where(is_run, s, cap)
-        take = is_run & (prog_ref[i] > bprog_ref[sl])
-        bprog_ref[sl] = jnp.where(take, prog_ref[i], bprog_ref[sl])
-        bpos_ref[sl] = jnp.where(take, jnp.asarray(i, jnp.int32),
-                                 bpos_ref[sl])
-        bstart_ref[sl] = jnp.where(take, start_ref[i], bstart_ref[sl])
-        hspec_ref[sl] = jnp.maximum(
-            hspec_ref[sl], jnp.where(is_run & sp, jnp.int32(1),
-                                     jnp.int32(0)))
-        # Collective winning rows: any running attempt of the task.
-        is_att = (att_ref[i] == 1) & (jls_ref[i] == j)
-        sa = jnp.where(is_att, s, cap)
-        hi_ref[sa] = jnp.maximum(hi_ref[sa],
-                                 jnp.where(is_att & sp, rate_ref[i], neg))
-        lo_ref[sa] = jnp.maximum(lo_ref[sa],
-                                 jnp.where(is_att & ~sp, rate_ref[i], neg))
-        sp2_ref[sa] = jnp.maximum(
-            sp2_ref[sa], jnp.where(is_att & sp, jnp.int32(1), jnp.int32(0)))
-        org_ref[sa] = jnp.maximum(
-            org_ref[sa], jnp.where(is_att & ~sp, jnp.int32(1),
-                                   jnp.int32(0)))
-        return nrows + jnp.where(is_run, 1, 0)
-
-    nrows = jax.lax.fori_loop(0, cap, body, 0)
-
-    # --- LATE percentile rank over the per-task candidates -------------
-    bpos = bpos_ref[:cap]
-    seg_ok = bpos < cap
-    best_prog = bprog_ref[:cap]
-    best_start = bstart_ref[:cap]
-    okm = seg_ok & (hspec_ref[:cap] == 0) \
-        & (now - best_start >= min_runtime)
-    rho = jnp.where(seg_ok, best_prog, 0.0) \
-        / jnp.maximum(now - best_start, 1e-9)
-    est = (1.0 - jnp.where(seg_ok, best_prog, 0.0)) \
-        / jnp.maximum(rho, 1e-9)
-    m = okm.astype(jnp.int32).sum()
-    srt = jnp.sort(jnp.where(okm, rho, jnp.inf))
-    thresh = np_percentile_sorted(srt, m, q, one)
-    slow = okm & (rho < thresh)
-    est_m = jnp.where(slow, est, neg)
-    vict = jnp.argmax(est_m)                 # first-of-max = lowest tseg
-    good = (nrows >= 2) & (m >= 2) & (est_m[vict] > neg)
-    vict_ref[0] = jnp.where(good, order_ref[bpos[vict]], jnp.int32(-1))
-
-    # --- collective winning verdict ------------------------------------
-    win_seg = (sp2_ref[:cap] == 1) \
-        & ((org_ref[:cap] == 0) | (hi_ref[:cap] > lo_ref[:cap] * win_factor))
-    win_ref[0] = win_seg.any().astype(jnp.int32)
+    row_spec = pl.BlockSpec((rb,), lambda t, k, *_: (k,),
+                            memory_space=pltpu.SMEM)
+    acc_spec = pl.BlockSpec((tbs, LANES), lambda t, k, *_: (t, 0))
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nt, nblk),
+            in_specs=[row_spec] * (1 + ncol),
+            out_specs=[acc_spec] * len(inits)),
+        out_shape=[jax.ShapeDtypeStruct((nt * tbs, LANES), dtype)]
+        * len(inits),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(blo.astype(jnp.int32), bhi.astype(jnp.int32), seg.astype(jnp.int32),
+      *cols)
+    return [o.reshape(-1)[:nb] for o in outs]
 
 
-def _reap_kernel(astate_ref, tseg_ref, live_ref, out_ref, done_ref):
-    cap = astate_ref.shape[0]
-    done_ref[...] = jnp.zeros((cap + 1,), done_ref.dtype)
-
-    def mark(i, carry):
-        live = live_ref[i] == 1
-        s = jnp.where(live, tseg_ref[i], cap)
-        done_ref[s] = jnp.maximum(
-            done_ref[s], jnp.where(live & (astate_ref[i] == 1),
-                                   jnp.int32(1), jnp.int32(0)))
-        return carry
-
-    jax.lax.fori_loop(0, cap, mark, 0)
-
-    def emit(i, carry):
-        live = live_ref[i] == 1
-        s = jnp.where(live, tseg_ref[i], cap)
-        out_ref[i] = jnp.where(
-            live & (astate_ref[i] == 0) & (done_ref[s] == 1),
-            jnp.int32(1), jnp.int32(0))
-        return carry
-
-    jax.lax.fori_loop(0, cap, emit, 0)
-
-
-# ---------------------------------------------------------------------------
-# Wrappers (same signatures as the jax backend entry points)
-# ---------------------------------------------------------------------------
 def _i32(x):
     return x.astype(jnp.int32)
 
 
-def _pallas_spatial(cols, nh, now, jcap):
+# ---------------------------------------------------------------------------
+# Row updates (one per kernel)
+# ---------------------------------------------------------------------------
+def _sum_update(hit, r, vals, accs):
+    # Masked buckets add 0.0 — a bitwise no-op on the accumulators.
+    out = [a + jnp.where(hit, v, 0.0) for a, v in zip(accs, vals)]
+    return out + [accs[-1] + jnp.where(hit, 1.0, 0.0)]
+
+
+def _late_update(hit, r, vals, accs):
+    prog, spec = vals
+    bprog, bpos, hspec = accs
+    # Strictly-greater update in ascending canonical order: first wins.
+    take = hit & (prog > bprog)
+    return [jnp.where(take, prog, bprog),
+            jnp.where(take, r.astype(bpos.dtype), bpos),
+            jnp.where(hit & (spec == 1), 1.0, hspec)]
+
+
+def _collective_update(hit, r, vals, accs):
+    rate, spec, jls = vals
+    hi, lo, has_spec, has_orig, wjl = accs
+    hs = hit & (spec == 1)
+    ho = hit & (spec == 0)
+    return [jnp.where(hs, jnp.maximum(hi, rate), hi),
+            jnp.where(ho, jnp.maximum(lo, rate), lo),
+            jnp.where(hs, 1.0, has_spec),
+            jnp.where(ho, 1.0, has_orig),
+            jnp.where(hit, jnp.maximum(wjl, jls.astype(wjl.dtype)), wjl)]
+
+
+def _mark_update(hit, r, vals, accs):
+    return [jnp.where(hit, 1.0, accs[0])]
+
+
+# ---------------------------------------------------------------------------
+# Entry points (same signatures as the jax cores, plus ``interpret``)
+# ---------------------------------------------------------------------------
+def pallas_spatial(cols, nh, now, jcap, interpret):
     p = prep(cols, now)
-    cap = p["cap"]
     n = nh.shape[0]
     rho = p["prog"] / jnp.maximum(now - p["start"], 1e-9)
-    fired = pl.pallas_call(
-        _spatial_kernel,
-        grid=(jcap,),
-        in_specs=[pl.BlockSpec((cap,), lambda j: (0,))] * 5
-        + [pl.BlockSpec(nh.shape, lambda j: (0, 0))],
-        out_specs=pl.BlockSpec((1, 2, n), lambda j: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((jcap, 2, n), jnp.bool_),
-        scratch_shapes=[
-            pltpu_vmem((2, n), jnp.float64),
-            pltpu_vmem((2, n), jnp.float64),
-        ],
-        interpret=INTERPRET,
-    )(rho, _i32(p["node"]), _i32(p["kind"]), _i32(p["jls"]),
-      _i32(p["running"]), _i32(nh))
-    return fired.any(axis=1)
+    seg = jnp.where(p["running"], (p["jls"] * 2 + p["kind"]) * n + p["node"],
+                    -1)
+    sums, counts = bucket_pass(_sum_update, [0.0, 0.0], seg, [rho],
+                               jcap * 2 * n, rho.dtype, tile=8,
+                               interpret=interpret)
+    return spatial_pick(sums, counts, nh, jcap)
 
 
-def _pallas_temporal(cols, now, samp, init, prevk, n_nodes):
+def pallas_temporal(cols, now, samp, init, prevk, n_nodes, interpret):
     p = prep(cols, now)
-    cap = p["cap"]
     jcap = samp.shape[0]
     n = n_nodes
-    m = p["running"]
-    samp_r = m & samp[p["jls"]]
-    init_r = m & init[p["jls"]]
-    alive = samp_r & (p["mark"] == prevk[p["jls"]])
-    zn, zp = pl.pallas_call(
-        _temporal_kernel,
-        grid=(jcap,),
-        in_specs=[pl.BlockSpec((cap,), lambda j: (0,))] * 5,
-        out_specs=(pl.BlockSpec((1, n), lambda j: (j, 0)),
-                   pl.BlockSpec((1, n), lambda j: (j, 0))),
-        out_shape=(jax.ShapeDtypeStruct((jcap, n), jnp.float64),
-                   jax.ShapeDtypeStruct((jcap, n), jnp.float64)),
-        scratch_shapes=[pltpu_vmem((n,), jnp.int32)],
-        interpret=INTERPRET,
-    )(p["prog"], jnp.where(alive, p["tprog"], 0.0), _i32(p["node"]),
-      _i32(p["jls"]), _i32(alive))
-    wmask = samp_r | init_r
-    newk = jnp.where(samp, prevk + 1, 0)
-    newmark = jnp.where(wmask, newk[p["jls"]], p["mark"])
-    newtprog = jnp.where(wmask, p["prog"], p["tprog"])
-    return zn, zp, wmask, newmark, newtprog
+    alive = temporal_alive(p, samp, prevk)
+    seg = jnp.where(alive, p["jls"] * n + p["node"], -1)
+    tprog = jnp.where(alive, p["tprog"], 0.0)
+    zn, zp, cnt = bucket_pass(_sum_update, [0.0, 0.0, 0.0], seg,
+                              [p["prog"], tprog], jcap * n,
+                              p["prog"].dtype, tile=8, interpret=interpret)
+    return temporal_pick(p, zn, zp, cnt, samp, init, prevk, jcap, n)
 
 
-def _late_call(cols, now, min_runtime, q, win_factor, jcap):
+def pallas_late(cols, now, min_runtime, q, jcap, interpret):
     p = prep(cols, now)
     cap = p["cap"]
+    fdt = p["prog"].dtype
+    seg = jnp.where(p["running"], p["tseg"], -1)
+    best, bpos, has_spec = bucket_pass(
+        _late_update, [-jnp.inf, float(cap), 0.0], seg,
+        [p["prog"], _i32(p["spec"])], cap, fdt, tile=8,
+        interpret=interpret)
+    return late_pick(p, best, _i32(bpos), has_spec > 0, now, min_runtime,
+                     q, jcap)
+
+
+def pallas_winning(cols, now, win_factor, jcap, interpret):
+    p = prep(cols, now)
+    cap = p["cap"]
+    fdt = p["prog"].dtype
+    m = p["active"] & (p["a_state"] == 0)    # running attempts, any task
     rate = p["prog"] / jnp.maximum(now - p["start"], 1e-9)
-    runatt = p["active"] & (p["a_state"] == 0)
-    params = jnp.stack([now, min_runtime, q, win_factor, cols["one"]])
-    f64 = jnp.float64
-    victims, win = pl.pallas_call(
-        _late_kernel,
-        grid=(jcap,),
-        in_specs=[pl.BlockSpec((cap,), lambda j: (0,))] * 9
-        + [pl.BlockSpec((5,), lambda j: (0,))],
-        out_specs=(pl.BlockSpec((1,), lambda j: (j,)),
-                   pl.BlockSpec((1,), lambda j: (j,))),
-        out_shape=(jax.ShapeDtypeStruct((jcap,), jnp.int32),
-                   jax.ShapeDtypeStruct((jcap,), jnp.int32)),
-        scratch_shapes=[
-            pltpu_vmem((cap + 1,), f64),        # best prog
-            pltpu_vmem((cap + 1,), jnp.int32),  # best pos
-            pltpu_vmem((cap + 1,), f64),        # best start
-            pltpu_vmem((cap + 1,), jnp.int32),  # has speculative
-            pltpu_vmem((cap + 1,), f64),        # max spec rate
-            pltpu_vmem((cap + 1,), f64),        # max orig rate
-            pltpu_vmem((cap + 1,), jnp.int32),  # any spec
-            pltpu_vmem((cap + 1,), jnp.int32),  # any orig
-        ],
-        interpret=INTERPRET,
-    )(p["prog"], p["start"], rate, _i32(p["spec"]),
-      _i32(p["tseg"]), _i32(p["jls"]), _i32(p["running"]), _i32(runatt),
-      _i32(cols["order"]), params)
-    return victims.astype(jnp.int64), win == 1
+    seg = jnp.where(m, p["tseg"], -1)
+    hi, lo, has_spec, has_orig, wjl = bucket_pass(
+        _collective_update, [-jnp.inf, -jnp.inf, 0.0, 0.0, -1.0], seg,
+        [rate, _i32(p["spec"]), _i32(p["jls"])], cap, fdt, tile=4,
+        interpret=interpret)
+    return winning_pick(has_spec > 0, has_orig > 0, hi, lo, _i32(wjl),
+                        win_factor, jcap)
 
 
-def _pallas_late(cols, now, min_runtime, q, jcap):
-    victims, _win = _late_call(cols, now, min_runtime, q,
-                               jnp.float64(1.0), jcap)
-    return victims
-
-
-def _pallas_winning(cols, now, win_factor, jcap):
-    _victims, win = _late_call(cols, now, jnp.float64(10.0),
-                               jnp.float64(25.0), win_factor, jcap)
-    return win
-
-
-def _pallas_reap(cols, now):
+def pallas_reap(cols, now, interpret):
     p = prep(cols, now)
     cap = p["cap"]
     live = p["active"] & (p["t_state"] == 2)
-    out = pl.pallas_call(
-        _reap_kernel,
-        out_shape=jax.ShapeDtypeStruct((cap,), jnp.int32),
-        scratch_shapes=[pltpu_vmem((cap + 1,), jnp.int32)],
-        interpret=INTERPRET,
-    )(_i32(p["a_state"]), _i32(p["tseg"]), _i32(live))
-    return out == 1
+    seg = jnp.where(live & (p["a_state"] == 1), p["tseg"], -1)
+    done, = bucket_pass(_mark_update, [0.0], seg, [], cap,
+                        p["prog"].dtype, tile=8, interpret=interpret)
+    return live & (done[p["tseg"]] > 0) & (p["a_state"] == 0)
 
 
-def pltpu_vmem(shape, dtype):
-    """VMEM scratch allocator — indirected so interpret mode works on
-    CPU-only installs where the TPU plugin may be absent."""
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
-
-
-_pallas_spatial_jit = jax.jit(_pallas_spatial, static_argnames=("jcap",))
-_pallas_temporal_jit = jax.jit(_pallas_temporal,
-                               static_argnames=("n_nodes",))
-_pallas_late_jit = jax.jit(_pallas_late, static_argnames=("jcap",))
-_pallas_winning_jit = jax.jit(_pallas_winning, static_argnames=("jcap",))
-_pallas_reap_jit = jax.jit(_pallas_reap)
+_jit = functools.partial(jax.jit, static_argnames=("jcap", "interpret"))
+_spatial_jit = _jit(pallas_spatial)
+_temporal_jit = jax.jit(pallas_temporal,
+                        static_argnames=("n_nodes", "interpret"))
+_late_jit = _jit(pallas_late)
+_winning_jit = _jit(pallas_winning)
+_reap_jit = jax.jit(pallas_reap, static_argnames=("interpret",))
 
 
 class PallasBackend(JaxBackend):
     """Device layout, upload discipline and host glue are inherited from
-    the jax backend; the hot reductions run as Pallas kernels."""
+    the jax backend; the segmented reductions run as Pallas kernels."""
 
     name = "pallas"
 
-    _spatial_fn = staticmethod(_pallas_spatial_jit)
-    _temporal_fn = staticmethod(_pallas_temporal_jit)
-    _late_fn = staticmethod(_pallas_late_jit)
-    _winning_fn = staticmethod(_pallas_winning_jit)
-    _reap_fn = staticmethod(_pallas_reap_jit)
+    def _spatial_fn(self, cols, nh, now, jcap):
+        return _spatial_jit(cols, nh, now, jcap=jcap, interpret=not on_tpu())
+
+    def _temporal_fn(self, cols, now, samp, init, prevk, n_nodes):
+        return _temporal_jit(cols, now, samp, init, prevk, n_nodes=n_nodes,
+                             interpret=not on_tpu())
+
+    def _late_fn(self, cols, now, min_runtime, q, jcap):
+        return _late_jit(cols, now, min_runtime, q, jcap=jcap,
+                         interpret=not on_tpu())
+
+    def _winning_fn(self, cols, now, win_factor, jcap):
+        return _winning_jit(cols, now, win_factor, jcap=jcap,
+                            interpret=not on_tpu())
+
+    def _reap_fn(self, cols, now):
+        return _reap_jit(cols, now, interpret=not on_tpu())
 
 
-__all__ = ["PallasBackend", "INTERPRET"]
+__all__ = ["PallasBackend", "bucket_pass", "pallas_late", "pallas_reap",
+           "pallas_spatial", "pallas_temporal", "pallas_winning"]

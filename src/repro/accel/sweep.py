@@ -23,7 +23,8 @@ perturbations rather than event-schedule edits:
 
 ``run_serial`` evaluates the identical clones one at a time on the
 numpy reference backend — the baseline the perf gate compares against,
-and the parity oracle for ``run_batched`` (bit-exact on CPU, §13.3).
+and the parity oracle for ``run_batched`` (bit-exact on CPU; on a TPU,
+float32 verdicts are held to the §13.3 tolerance).
 """
 from __future__ import annotations
 
@@ -200,23 +201,25 @@ class BatchedSweep:
         """All scenarios in one vmapped device step."""
         assert self._stacked is not None, "call prepare() first"
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.accel.jax_backend import precision, scalar, to_device
         J = len(self.active)
-        with enable_x64():
-            cols = {k: jnp.asarray(v) for k, v in self._stacked.items()}
+        with precision():
+            cols = to_device(self._stacked, self.now)
+            f = scalar(0.0).dtype
             out = _sweep_jit(self._jcap)(
-                cols, jnp.asarray(self.neighborhoods),
-                jnp.float64(self.now), jnp.float64(self.min_runtime),
-                jnp.float64(self.slow_task_percentile),
-                jnp.float64(self.win_factor), jnp.asarray(self.declared),
-                jnp.asarray(self.thresholds),
-                jnp.float64(self.responsive_window))
+                cols, jnp.asarray(self.neighborhoods, jnp.int32),
+                scalar(0.0), scalar(self.min_runtime),
+                scalar(self.slow_task_percentile),
+                scalar(self.win_factor), jnp.asarray(self.declared),
+                jnp.asarray(self.thresholds, f),
+                scalar(self.responsive_window))
         host = {k: np.asarray(v) for k, v in out.items()}
         return [
             {
                 "spatial_hits": host["spatial_hits"][i][:J],
                 "failed": host["failed"][i],
-                "late_victims": host["late_victims"][i][:J],
+                "late_victims": host["late_victims"][i][:J].astype(
+                    np.int64),
                 "winning": host["winning"][i][:J],
                 "n_reap": int(host["n_reap"][i]),
             }

@@ -487,7 +487,8 @@ class DeviceColumns:
       reduction;
     - the canonical row order (:meth:`ArraySnapshot.order`) is exported
       zero-padded, so device segmented reductions visit live rows in
-      exactly the reference accumulation order (§11.3);
+      exactly the reference accumulation order (§11.3), together with
+      each canonical position's task segment id (``tseg``);
     - the job axis is padded the same way (``jobs_cap`` for the
       job-registry axis, ``jcap`` for the active-job output axis).
 
@@ -503,8 +504,7 @@ class DeviceColumns:
         "a_state": 0, "t_state": 0, "kind": 0, "job": 0, "node": 0,
         "spec": False, "start": 0.0, "work_done": 0.0, "work_total": 1.0,
         "last_sync": 0.0, "fetched": 0, "deps": 1, "compute": False,
-        "active": False, "skey": 0, "sh_ready": 0, "sh_inflight": 0,
-        "sh_fail": 0,
+        "active": False, "sh_ready": 0, "sh_inflight": 0, "sh_fail": 0,
     }
 
     def __init__(self, arr: ArraySnapshot):
@@ -560,6 +560,13 @@ class DeviceColumns:
         self._order_buf[:n] = order
         if clear_to > n:
             self._order_buf[n:clear_to] = 0
+        # Task segment id per canonical position (task segments are
+        # contiguous in canonical order); pads get one id past the last.
+        torder = arr.skey[order] // _KEY_STRIDE
+        tseg = np.full(cap, 0, dtype=np.int64)
+        if n:
+            tseg[1:n] = np.cumsum(torder[1:] != torder[:-1])
+            tseg[n:] = tseg[n - 1] + 1
         self._last_n = n
         # Job axes: the registry axis (job_local gather) and the active
         # axis (per-job kernel outputs) both grow by doubling.
@@ -575,6 +582,7 @@ class DeviceColumns:
         out: Dict[str, object] = dict(self._buf)
         out.update(self._scratch_buf)
         out["order"] = self._order_buf
+        out["tseg"] = tseg
         out["job_local"] = self._jl_buf
         out["n_rows"] = n
         out["n_jobs"] = len(active)

@@ -20,7 +20,7 @@ Notation follows §III.A:
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -28,6 +28,7 @@ __all__ = [
     "node_progress_rate_np",
     "spatial_slow_mask_np",
     "spatial_slow_mask_batch_np",
+    "spatial_stats_batch_np",
     "temporal_slow_mask_np",
     "eq4_estimate_np",
     "eq4_estimate_weights",
@@ -79,10 +80,12 @@ def spatial_slow_mask_np(P: np.ndarray, neighborhoods: np.ndarray
     return ok & (P < (mean - std))
 
 
-def spatial_slow_mask_batch_np(P: np.ndarray, neighborhoods: np.ndarray
-                               ) -> np.ndarray:
-    """Eq. 1 batched over assessment groups: ``P`` is (groups, n_nodes) —
-    one row per (job, phase) — and the result is (groups, n_nodes).
+def spatial_stats_batch_np(P: np.ndarray, neighborhoods: np.ndarray
+                           ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Eq. 1's scores batched over assessment groups: ``P`` is (groups,
+    n_nodes) — one row per (job, phase) — and the result is ``(mean,
+    std, ok)``, each (groups, n_nodes): the neighborhood mean and σ, and
+    the gate (≥ 2 live neighbors and a live ``P``).
 
     Operation-for-operation identical to :func:`spatial_slow_mask_np`
     applied per row (same nansum element order, same clip constants), so
@@ -98,6 +101,13 @@ def spatial_slow_mask_batch_np(P: np.ndarray, neighborhoods: np.ndarray
             / np.maximum(cnt, 1)
     std = np.sqrt(var)
     ok = (cnt >= 2) & ~np.isnan(P)
+    return mean, std, ok
+
+
+def spatial_slow_mask_batch_np(P: np.ndarray, neighborhoods: np.ndarray
+                               ) -> np.ndarray:
+    """Eq. 1 batched over assessment groups: (groups, n_nodes) hits."""
+    mean, std, ok = spatial_stats_batch_np(P, neighborhoods)
     return ok & (P < (mean - std))
 
 

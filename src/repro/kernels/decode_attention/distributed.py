@@ -131,11 +131,7 @@ def dist_decode_update_attend(
         in_specs=(pspec_bhd, pspec_bhd, pspec_bhd,
                   pspec_cache, pspec_cache, P(bspec)),
         out_specs=(pspec_bhd, pspec_cache, pspec_cache))
-    if hasattr(jax, "shard_map"):  # jax ≥ 0.6
-        mapped = jax.shard_map(body, check_vma=False, **specs)
-    else:  # older jax: same semantics under the experimental name
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(body, check_rep=False, **specs)
+    mapped = jax.shard_map(body, check_vma=False, **specs)
     return mapped(q, new_k, new_v, cache_k, cache_v, pos)
 
 

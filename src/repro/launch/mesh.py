@@ -10,17 +10,10 @@ import math
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax ≥ 0.5; older releases default every axis to Auto anyway
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _axis_type_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

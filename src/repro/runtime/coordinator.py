@@ -608,11 +608,14 @@ class Coordinator:
 
         # ---- reduce: deterministic ordered sum + optimizer apply -------
         ordered = [grads[k] for k in sorted(grads)]
+        denom = float(self.n_shards * M)
         total = jax.tree.map(
             lambda *xs: sum(x.astype(np.float32) if hasattr(x, "astype")
-                            else x for x in xs), *ordered)
-        denom = float(self.n_shards * M)
-        total = jax.tree.map(lambda x: x / denom, total)
+                            else x for x in xs) / denom, *ordered)
+        # Release the per-microbatch trees before the optimizer builds the
+        # new state: at full model width they are most of the device.
+        del ordered
+        grads.clear()
         self.state = self.apply_fn(self.state, total)
         for s in range(self.n_shards):
             self.datastates[s] = self.datastates[s].advance(M)

@@ -16,9 +16,10 @@ Delivery is at-least-once: the coordinator redelivers unacknowledged
 seen-set to make redelivery idempotent (§16.5). All time flows through
 an injected :class:`repro.runtime.clock.Clock`.
 
-The JAX computation itself runs in-process (one CPU device stands in for
-every host's chip); what is REAL here is the control plane the paper is
-about: heartbeats, progress logs, speculative reassignment, rollback.
+The JAX computation itself runs in-process: one device — the CPU, or
+the one TPU chip — stands in for every host's chip, and the host threads
+share it. What is REAL here is the control plane the paper is about:
+heartbeats, progress logs, speculative reassignment, rollback.
 """
 from __future__ import annotations
 
@@ -173,8 +174,19 @@ class HostDaemon(threading.Thread):
             self.clock.sleep(self.heartbeat_period)
 
     def run(self) -> None:
-        threading.Thread(target=self._hb_loop, daemon=True,
-                         name=f"hb-{self.host_id}").start()
+        hb = threading.Thread(target=self._hb_loop, daemon=True,
+                              name=f"hb-{self.host_id}")
+        hb.start()
+        try:
+            self._serve()
+        finally:
+            # Joining the daemon joins its heartbeat thread too: once
+            # ``join`` returns nothing of this host still references the
+            # coordinator (or, through it, the model state on the device).
+            self._halt.set()
+            hb.join()
+
+    def _serve(self) -> None:
         while not self._halt.is_set():
             try:
                 item = self._work.get(timeout=self.heartbeat_period)

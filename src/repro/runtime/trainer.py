@@ -56,6 +56,9 @@ class TrainerRuntime:
             return loss + aux, {"loss": loss}
 
         grad_val = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))
+        # The jitted microbatch step, exposed for AOT inspection
+        # (``grad_step.lower(params, batch).compile().memory_analysis()``).
+        self.grad_step = grad_val
 
         def grad_fn(params, batch):
             (_, metrics), grads = grad_val(params, batch)

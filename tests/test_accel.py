@@ -253,21 +253,68 @@ def test_scenario_grid_deterministic():
 # 4. Unit behaviours
 # ---------------------------------------------------------------------------
 def test_percentile_mirror_matches_numpy():
+    # LATE's per-job percentile: exact order statistics + numpy's lerp.
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    from repro.accel.jax_backend import np_percentile_sorted
+    from repro.accel.jax_backend import member_percentile
     rng = np.random.default_rng(0)
-    with enable_x64():
+    with jax.enable_x64(True):
         for m in list(range(1, 24)) + [101]:
             vals = rng.random(m) * rng.choice([1e-6, 1.0, 1e6])
-            srt = np.sort(vals)
-            padded = np.concatenate([srt, np.full(7, np.inf)])
+            padded = np.concatenate([vals, rng.random(7)])
+            member = np.zeros((2, m + 7), dtype=bool)
+            member[0, :m] = True                # job 0: the m values
             for q in (25.0, 50.0, 75.0, 90.0):
-                got = float(np_percentile_sorted(
-                    jnp.asarray(padded), jnp.int64(m), jnp.float64(q),
-                    jnp.float64(1.0)))
+                got, cnt = member_percentile(
+                    jnp.asarray(member), jnp.asarray(padded),
+                    jnp.float64(q), jnp.float64(1.0))
                 want = float(np.percentile(vals, q))
-                assert got == want, (m, q, got, want)
+                assert float(got[0]) == want, (m, q, float(got[0]), want)
+                assert int(cnt[0]) == m and int(cnt[1]) == 0
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+def test_order_stat_matches_sorted(dtype):
+    # The sort-free order statistics behind LATE's percentile: exact
+    # k-th smallest per job, in both precisions the platforms use.
+    import jax
+    import jax.numpy as jnp
+    from repro.accel.jax_backend import order_stat
+    rng = np.random.default_rng(3)
+    cap, jcap = 64, 3
+    with jax.enable_x64(dtype == "float64"):
+        vals = (rng.random(cap) * rng.choice([1e-6, 1.0, 1e6], cap)
+                ).astype(dtype)
+        vals[::7] = vals[1]                     # ties
+        vals[5] = 0.0
+        keys = rng.integers(0, jcap + 1, cap)   # jcap = not a member
+        member = keys[None, :] == np.arange(jcap)[:, None]
+        ks = np.array([[0, 1, 2], [3, 0, 5], [60, 2, 63]])
+        got = np.asarray(order_stat(jnp.asarray(member), jnp.asarray(vals),
+                                    jnp.asarray(ks)))
+    for i in range(len(ks)):
+        for j in range(jcap):
+            srt = np.sort(vals[keys == j])
+            if ks[i, j] < len(srt):
+                assert got[i, j] == srt[ks[i, j]], (i, j)
+            else:
+                assert got[i, j] == np.inf, (i, j)
+
+
+def test_shadow_tolerates_only_near_threshold_flips():
+    # §13.3 tolerance: a flipped Eq. 4 verdict is tolerated only where
+    # the reference's silence sits within TOL of the threshold.
+    from repro.accel.shadow import Tally, check_failure
+    now, window = 100.0, 1.5
+    hb = np.array([90.0, 90.0, 99.0])
+    thresholds = np.array([10.0 * (1 + 1e-9), 5.0, 10.0])
+    ref = np.array([False, True, False])
+    dev = np.array([True, False, False])     # node 0 near, node 1 firm
+    tally = Tally()
+    check_failure(tally, dev, ref, now, hb, thresholds, window, "failed")
+    assert tally.counts["failed"] == {"equal": 1, "tolerated": 1,
+                                      "outside": 1}
+    assert tally.outside() == 1 and tally.examples
 
 
 def test_backend_registry():
