@@ -53,11 +53,11 @@ FLEET_POLICIES = ("yarn", "bino", "budgeted", "clone")
 # list, full rescan per dispatch, per-request heap query, O(pending)
 # has_queued / watchdog set. Subclasses the plane only to inherit the
 # Simulation-facing surface; every hot method is the old code plus the
-# profile counters the new plane exposes.
+# pass counters the new plane exposes.
 # ---------------------------------------------------------------------------
 class LegacyLinearDispatcher(Dispatcher):
     def __init__(self, sim):
-        super().__init__(sim, profile=True)
+        super().__init__(sim)
         self._pending: List[LaunchRequest] = []
 
     @property
@@ -88,7 +88,6 @@ class LegacyLinearDispatcher(Dispatcher):
 
     def dispatch(self) -> None:
         sim = self.sim
-        t0 = time.perf_counter()
         still: List[LaunchRequest] = []
         for req in self._pending:
             task = req.task
@@ -109,7 +108,6 @@ class LegacyLinearDispatcher(Dispatcher):
             sim._start_attempt(req, node_id)
         self._pending = still
         self.n_scalar_passes += 1
-        self.decision_wall += time.perf_counter() - t0
 
     def watchdog(self) -> None:
         sim = self.sim
@@ -138,6 +136,23 @@ class LegacyLinearDispatcher(Dispatcher):
 # ---------------------------------------------------------------------------
 # Part A: plane cost per decision
 # ---------------------------------------------------------------------------
+def _timed(obj, name: str) -> Dict[str, float]:
+    """Accumulate the wall seconds of every call of ``obj.<name>`` into
+    the returned ``{"s": ...}``."""
+    wall = {"s": 0.0}
+    inner = getattr(obj, name)
+
+    def timed(*args):
+        t0 = time.perf_counter()
+        try:
+            return inner(*args)
+        finally:
+            wall["s"] += time.perf_counter() - t0
+
+    setattr(obj, name, timed)
+    return wall
+
+
 def _burst_specs(n_workers: int) -> List[JobSpec]:
     """A same-instant burst of concurrent jobs sized to ~4 map splits
     per worker in total (PR 7's proportional shape, split across
@@ -153,32 +168,25 @@ def measure_plane(n_workers: int, plane: str, *, sim_seconds: float,
                   seed: int = 0) -> Dict:
     """Kernel-mode burst with 2 containers/worker — demand is 2× the
     slot count, so pending queues stay deep and the cluster sits full
-    (the PR 7 profile's regime). ``decision_wall`` brackets the whole
+    (the PR 7 profile's regime). The dispatch wall brackets every
     placement pass; attempt *construction* (``_start_attempt``) is
     identical under both planes and timed out of the metric."""
     params = dataclasses.replace(SimParams(), sim_time_cap=sim_seconds)
     sim = Simulation(policy="yarn", seed=seed, n_workers=n_workers,
-                     n_containers=2, params=params, shuffle="kernel",
-                     dispatch_opts={"profile": True})
+                     n_containers=2, params=params, shuffle="kernel")
     if plane == "legacy":
         sim.sched = LegacyLinearDispatcher(sim)
-    construct = {"s": 0.0}
-    orig = sim._start_attempt
-
-    def timed(req, node_id):
-        c0 = time.perf_counter()
-        r = orig(req, node_id)
-        construct["s"] += time.perf_counter() - c0
-        return r
-
-    sim._start_attempt = timed
+    # Both wrappers sit on the instances, so every caller (the sim and
+    # the dispatcher's own watchdog) goes through them.
+    dispatch = _timed(sim.sched, "dispatch")
+    construct = _timed(sim, "_start_attempt")
     for spec in _burst_specs(n_workers):
         sim.submit(spec)
     t0 = time.perf_counter()
     sim.run()
     wall = time.perf_counter() - t0
     sched = sim.sched
-    plane_wall = max(sched.decision_wall - construct["s"], 1e-9)
+    plane_wall = max(dispatch["s"] - construct["s"], 1e-9)
     # The comparable unit is the granted launch — both planes issue the
     # same ~N grants for this workload. Normalizing by placement
     # *attempts* would flatter the legacy pass, which burns millions of

@@ -43,6 +43,7 @@ swaps the segmented reductions for hand-written kernels and reuses the
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -52,6 +53,7 @@ import jax.numpy as jnp
 
 from repro.accel.base import TMARK, TPROG, AssessmentBackend
 from repro.core.arrays import SHUFFLE_FRACTION, ArraySnapshot, DeviceColumns
+from repro.obs.metrics import span
 
 
 # ---------------------------------------------------------------------------
@@ -468,6 +470,24 @@ _winning_jit = jax.jit(winning_core, static_argnames=("jcap",))
 _reap_jit = jax.jit(reap_core)
 
 
+def _spanned(method):
+    """Run a backend method inside the span ``repro.accel.<method>``."""
+    name = "accel." + method.__name__
+
+    @functools.wraps(method)
+    def spanned(self, *args, **kwargs):
+        with span(name):
+            return method(self, *args, **kwargs)
+    return spanned
+
+
+def _launch(fn, *args):
+    """Dispatch one jitted or Pallas program; the device runs it after
+    this returns."""
+    with span("accel.launch"):
+        return fn(*args)
+
+
 class JaxBackend(AssessmentBackend):
     name = "jax"
 
@@ -480,6 +500,7 @@ class JaxBackend(AssessmentBackend):
         self._nh_dev = None
         self._nh_host = None
         self.upload_bytes = 0     # bytes of the last per-tick upload
+        self.fetch_bytes = 0      # bytes copied to the host, cumulative
 
     # Entry points — the pallas subclass overrides the segmented passes.
     def _spatial_fn(self, cols, nh, now, jcap):
@@ -509,8 +530,9 @@ class JaxBackend(AssessmentBackend):
             self._dc = DeviceColumns(arr)
         arr.scratch(TMARK, np.int64, -1)
         arr.scratch(TPROG, np.float64, np.nan)
-        host = self._dc.refresh(active, scratch_names=(TMARK, TPROG))
-        with precision():
+        with span("accel.refresh"):
+            host = self._dc.refresh(active, scratch_names=(TMARK, TPROG))
+        with precision(), span("accel.upload"):
             dev = to_device(host, now)
         self.upload_bytes = sum(int(v.nbytes) for v in dev.values())
         out = (dev, self._dc.jcap)
@@ -524,14 +546,36 @@ class JaxBackend(AssessmentBackend):
             self._nh_host = neighborhoods
         return self._nh_dev
 
+    @staticmethod
+    def _wait(*outs) -> None:
+        """Block until the device has computed ``outs``. Their copies to
+        the host are queued first, so the device starts them as soon as
+        it is done: one wait for all of them, not one per array."""
+        for x in outs:
+            x.copy_to_host_async()
+        with span("accel.wait"):
+            jax.block_until_ready(outs)
+
+    def _fetch(self, *outs, dtype=None) -> list:
+        """Device arrays on the host (``dtype`` converts there): the rest
+        of their copies, and the conversion."""
+        with span("accel.fetch"):
+            host = [np.asarray(x, dtype=dtype) for x in outs]
+        self.fetch_bytes += sum(int(x.nbytes) for x in outs)
+        return host
+
     # ------------------------------------------------------------------
+    @_spanned
     def spatial_hits(self, arr, now, active, neighborhoods):
         cols, jcap = self._cols(arr, now, active)
+        nh = self._nh(neighborhoods)
         with precision():
-            hits = self._spatial_fn(cols, self._nh(neighborhoods),
-                                    scalar(0.0), jcap)
-        return np.asarray(hits)[:len(active)]
+            hits = _launch(self._spatial_fn, cols, nh, scalar(0.0), jcap)
+        self._wait(hits)
+        hits, = self._fetch(hits)
+        return hits[:len(active)]
 
+    @_spanned
     def temporal_zeta(self, arr, now, active, samp_flag, init_flag, prevk):
         cols, jcap = self._cols(arr, now, active)
         J = len(active)
@@ -543,40 +587,49 @@ class JaxBackend(AssessmentBackend):
         prevkd = np.full(jcap, -2, dtype=np.int32)
         prevkd[:J] = prevk
         with precision():
-            zn, zp, wmask, newmark, newtprog = self._temporal_fn(
-                cols, scalar(0.0), jnp.asarray(sampd),
-                jnp.asarray(initd), jnp.asarray(prevkd), n)
+            args = (cols, scalar(0.0), jnp.asarray(sampd),
+                    jnp.asarray(initd), jnp.asarray(prevkd), n)
+            outs = _launch(self._temporal_fn, *args)
+        zn, zp, wmask, newmark, newtprog = outs
+        self._wait(zn, zp, wmask)   # the marks are copied only if used
+        zn, zp = self._fetch(zn, zp, dtype=np.float64)
         # Scratch write-back: the device computed this sample's marks in
         # canonical order; apply them to the host columns.
         n_rows = arr.n
-        w = np.asarray(wmask)[:n_rows]
+        w = self._fetch(wmask)[0][:n_rows]
         if w.any():
+            newmark, newtprog = self._fetch(newmark, newtprog)
             rows = arr.order()[w]
-            arr.scratch(TMARK, np.int64, -1)[rows] = \
-                np.asarray(newmark)[:n_rows][w]
+            arr.scratch(TMARK, np.int64, -1)[rows] = newmark[:n_rows][w]
             arr.scratch(TPROG, np.float64, np.nan)[rows] = \
-                np.asarray(newtprog)[:n_rows][w]
-        return (np.asarray(zn, dtype=np.float64)[:J],
-                np.asarray(zp, dtype=np.float64)[:J])
+                newtprog[:n_rows][w]
+        return zn[:J], zp[:J]
 
+    @_spanned
     def failure_masks(self, now, node_hb, node_marked, declared,
                       thresholds, responsive_window):
         with precision():
             f = scalar(0.0).dtype
-            resp, cand = _failure_jit(
-                scalar(0.0), jnp.asarray(np.asarray(node_hb) - now, f),
-                jnp.asarray(node_marked), jnp.asarray(declared),
-                jnp.asarray(thresholds, f), scalar(responsive_window))
-        return np.asarray(resp), np.asarray(cand)
+            args = (scalar(0.0), jnp.asarray(np.asarray(node_hb) - now, f),
+                    jnp.asarray(node_marked), jnp.asarray(declared),
+                    jnp.asarray(thresholds, f), scalar(responsive_window))
+            resp, cand = _launch(_failure_jit, *args)
+        self._wait(resp, cand)
+        return tuple(self._fetch(resp, cand))
 
+    @_spanned
     def late_victims(self, arr, now, active, eligible, min_runtime,
                      slow_task_percentile):
         cols, jcap = self._cols(arr, now, active)
         with precision():
-            victims = self._late_fn(cols, scalar(0.0), scalar(min_runtime),
-                                    scalar(slow_task_percentile), jcap)
-        return np.asarray(victims, dtype=np.int64)[:len(active)]
+            victims = _launch(self._late_fn, cols, scalar(0.0),
+                              scalar(min_runtime),
+                              scalar(slow_task_percentile), jcap)
+        self._wait(victims)
+        victims, = self._fetch(victims, dtype=np.int64)
+        return victims[:len(active)]
 
+    @_spanned
     def winning(self, arr, now, job_idx, win_factor):
         active = arr.active_jobs()
         if self._win_memo[0] == now and self._win_memo[1] == win_factor \
@@ -585,8 +638,10 @@ class JaxBackend(AssessmentBackend):
         else:
             cols, jcap = self._cols(arr, now, active)
             with precision():
-                win = np.asarray(self._winning_fn(
-                    cols, scalar(0.0), scalar(win_factor), jcap))
+                win = _launch(self._winning_fn, cols, scalar(0.0),
+                              scalar(win_factor), jcap)
+            self._wait(win)
+            win, = self._fetch(win)
             self._win_memo = (now, win_factor, win, arr)
         jl = arr.job_local_map(active)
         pos = jl[job_idx] if 0 <= job_idx < len(jl) else -1
@@ -594,13 +649,15 @@ class JaxBackend(AssessmentBackend):
             return False
         return bool(win[pos])
 
+    @_spanned
     def reap_rows(self, arr, now):
         active = arr.active_jobs()
         cols, _jcap = self._cols(arr, now, active)
         with precision():
-            reap = self._reap_fn(cols, scalar(0.0))
-        mask = np.asarray(reap)[:arr.n]
-        return arr.order()[mask]
+            reap = _launch(self._reap_fn, cols, scalar(0.0))
+        self._wait(reap)
+        mask, = self._fetch(reap)
+        return arr.order()[mask[:arr.n]]
 
 
 __all__ = [

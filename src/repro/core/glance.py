@@ -33,6 +33,7 @@ import numpy as np
 from repro.accel.base import AssessmentBackend, get_backend
 from repro.core import metrics as M
 from repro.core.types import AttemptState, ClusterSnapshot, TaskKind, TaskState
+from repro.obs.metrics import span
 from repro.obs.trace import (
     K_GLANCE_FAIL,
     K_GLANCE_SPATIAL,
@@ -168,6 +169,10 @@ class NeighborhoodGlance:
     # Assessment tick
     # ------------------------------------------------------------------
     def assess(self, snap: ClusterSnapshot) -> GlanceVerdict:
+        with span("core.glance"):
+            return self._assess(snap)
+
+    def _assess(self, snap: ClusterSnapshot) -> GlanceVerdict:
         arr = getattr(snap, "arrays", None)
         if arr is not None:
             return self._assess_arrays(snap, arr)

@@ -35,6 +35,7 @@ from repro.core.types import (
     TaskState,
     TaskView,
 )
+from repro.obs.metrics import span
 from repro.obs.trace import K_BUDGET, K_LATE
 
 
@@ -511,39 +512,42 @@ class BinocularSpeculator(Speculator):
             slow_by_node.setdefault(node, reason)
         self._unhealthy = failed | set(slow_by_node)
 
-        # 2. Dependency awareness: completed producers on dead nodes, and
-        #    fetch-failure streaks, trigger producer re-execution.
-        dep_actions = self.dependency.on_node_failed(snap, failed)
-        dep_actions += self.dependency.on_fetch_failures(
-            snap, snap.fetch_failures)
+        # 2-6 plan the tick's launches from the verdict.
+        with span("core.plan"):
+            # 2. Dependency awareness: completed producers on dead nodes,
+            #    and fetch-failure streaks, trigger producer re-execution.
+            dep_actions = self.dependency.on_node_failed(snap, failed)
+            dep_actions += self.dependency.on_fetch_failures(
+                snap, snap.fetch_failures)
 
-        # 3. Straggler set: running tasks on slow/failed nodes.
-        arr = getattr(snap, "arrays", None)
-        if arr is not None:
-            stragglers = self._stragglers_arrays(
-                snap, arr, failed, slow_by_node)
-        else:
-            stragglers = self._stragglers_reference(
-                snap, failed, slow_by_node)
+            # 3. Straggler set: running tasks on slow/failed nodes.
+            arr = getattr(snap, "arrays", None)
+            if arr is not None:
+                stragglers = self._stragglers_arrays(
+                    snap, arr, failed, slow_by_node)
+            else:
+                stragglers = self._stragglers_reference(
+                    snap, failed, slow_by_node)
 
-        # 4. Collective ramp over the straggler wave, neighborhood-first.
-        nh = {n: self.glance.neighbors_of(n) for n in
-              {v for _, v, _ in stragglers if v is not None}}
-        launches = self.collective.plan(snap, stragglers, nh)
+            # 4. Collective ramp over the straggler wave,
+            #    neighborhood-first.
+            nh = {n: self.glance.neighbors_of(n) for n in
+                  {v for _, v, _ in stragglers if v is not None}}
+            launches = self.collective.plan(snap, stragglers, nh)
 
-        # Dependency re-executions bypass the ramp: they gate job progress
-        # (a reducer is already blocked on the lost output).
-        launches = list(dep_actions) + launches
+            # Dependency re-executions bypass the ramp: they gate job
+            # progress (a reducer is already blocked on the lost output).
+            launches = list(dep_actions) + launches
 
-        # 5. Rollback: race a resume-from-log attempt where the log's node
-        #    is healthy.
-        if self.cfg.rollback_enabled:
-            launches = plan_rollback(snap, self.rollback, launches,
-                                     self._unhealthy)
-        actions.extend(launches)
+            # 5. Rollback: race a resume-from-log attempt where the log's
+            #    node is healthy.
+            if self.cfg.rollback_enabled:
+                launches = plan_rollback(snap, self.rollback, launches,
+                                         self._unhealthy)
+            actions.extend(launches)
 
-        # 6. Reap siblings of completed attempts.
-        actions.extend(self.collective.reap_completed(snap))
+            # 6. Reap siblings of completed attempts.
+            actions.extend(self.collective.reap_completed(snap))
         return actions
 
     # ------------------------------------------------------------------

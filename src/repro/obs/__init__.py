@@ -6,7 +6,8 @@ identical structured-numpy records through a :class:`TraceRecorder`
 (near-zero cost when absent — one ``is not None`` branch per site),
 the :class:`MetricsRegistry` replaces scattered benchmark timers, and
 the exporters/scorecard turn traces into Perfetto timelines and
-detection-quality numbers.
+detection-quality numbers. :func:`span` names host spans in the JAX
+profiler's trace, beside the device's operations.
 """
 from repro.obs.export import to_chrome_trace, trace_diff, write_chrome_trace
 from repro.obs.metrics import (
@@ -16,6 +17,7 @@ from repro.obs.metrics import (
     MetricsRegistry,
     Timer,
     instrument_drain,
+    span,
 )
 from repro.obs.scorecard import attempt_outcomes, comparable_core, scorecard
 from repro.obs.trace import (
@@ -64,7 +66,7 @@ __all__ = [
     "ACT_MARK_FAILED", "ACT_SPECULATE", "ACT_KILL",
     "END_COMPLETED", "END_FAILED", "END_KILLED",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "Timer",
-    "instrument_drain",
+    "instrument_drain", "span",
     "to_chrome_trace", "write_chrome_trace", "trace_diff",
     "scorecard", "comparable_core", "attempt_outcomes",
 ]

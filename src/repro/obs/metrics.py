@@ -6,11 +6,36 @@ PR 7's ``attach_drain_timer`` dict lives here now as
 :func:`instrument_drain` — and gives the live coordinator a place to
 count recovery work that both ``benchmarks/perf_runtime.py`` and tests
 can read without reaching into internals.
+
+:func:`span` is its clock-side companion: a named host span in the JAX
+profiler's trace (DESIGN.md §18.7).
 """
 from __future__ import annotations
 
+import contextlib
+import sys
 import time
 from typing import Dict, Optional
+
+SPAN_PREFIX = "repro."
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **args):
+    """A host span ``repro.<name>`` in the profiler's trace, on the same
+    clock as the device's operations: a thin
+    ``jax.profiler.TraceAnnotation``. ``args`` (``tick=``, ``step=``)
+    become the event's stats; they are formatted only while a trace is
+    active. With no trace active a span costs one to two microseconds,
+    so it belongs at sites that fire at most about a thousand times per
+    second; per-event paths keep counters.
+
+    Without jax imported no trace can be active, and the span is a no-op
+    that does not import it."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _NO_SPAN
+    return profiler.TraceAnnotation(SPAN_PREFIX + name, **args)
 
 
 class Counter:

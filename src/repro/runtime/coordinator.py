@@ -85,7 +85,7 @@ from repro.core.arrays import ArraySnapshot
 from repro.core.collective import CollectiveConfig
 from repro.core.glance import GlanceConfig
 from repro.data.pipeline import DataState
-from repro.obs.metrics import MetricsRegistry
+from repro.obs.metrics import MetricsRegistry, span
 from repro.obs.trace import (
     END_COMPLETED,
     END_FAILED,
@@ -325,6 +325,10 @@ class Coordinator:
     # One training step
     # ------------------------------------------------------------------
     def run_step(self, step: int) -> StepReport:
+        with span("runtime.step", step=step):
+            return self._run_step(step)
+
+    def _run_step(self, step: int) -> StepReport:
         t0 = self.clock.time()
         recoveries: List[str] = []
         restarts = 0
@@ -526,101 +530,109 @@ class Coordinator:
                          speculative=False, rollback=False,
                          data_state=shard_states[s])
 
-        last_tick = 0.0
-        last_grad = self.clock.time()
-        auto = max(60.0, 30 * self.cfg.restart_timeout)
-        deadline = self.clock.time() + (self.cfg.step_deadline or auto)
-        while len(grads) < self.n_shards * M:
-            now = self.clock.time()
-            if now > deadline:
-                self._abort_inflight(step, attempts)
-                return False, mb_executed, {}, "deadline exceeded"
-            if len(self.live_hosts()) < self._quorum():
-                self._abort_inflight(step, attempts)
-                return False, mb_executed, {}, "quorum lost"
-            try:
-                msg = self.queue.get(timeout=0.02)
-            except queue.Empty:
-                msg = None
-            if isinstance(msg, GradMessage):
-                if msg.step != step:
-                    continue  # stale stream from a previous step's loser
-                key = (msg.shard_id, msg.mb_index)
-                mb_executed += 1
-                rec = attempts.get(msg.attempt_id)
-                if rec is not None:
-                    rec.last_seen = self.clock.time()
-                if key not in grads:  # exactly-once: first writer wins
-                    grads[key] = msg.grads
-                    for k, v in msg.metrics.items():
-                        metric_acc[k] = metric_acc.get(k, 0.0) + v
-                    tid = f"s{step}_grad{msg.shard_id:03d}"
-                    t = tasks.get(tid)
-                    if t is not None:
-                        t["last_grad"] = self.clock.time()
-                        last_grad = t["last_grad"]
-                        # Coverage decides completion — never an attempt's
-                        # own done-claim, which can vanish in transit.
-                        if not t["done"]:
-                            have = sum(1 for (s, _m) in grads
-                                       if s == msg.shard_id)
-                            if have >= M:
-                                self._mark_task_done(tasks, tid)
-            elif isinstance(msg, ProgressMessage):
-                if msg.step != step:
-                    continue
-                rec = attempts.get(msg.attempt_id)
-                if rec is not None and rec.state == AttemptState.RUNNING:
-                    # max(): chaos can reorder adjacent reports
-                    rec.mb_done = max(rec.mb_done, msg.mb_done)
-                    rec.last_seen = self.clock.time()
-                    if self.arr is not None and rec.row >= 0:
-                        self.arr.sync_row(rec.row, float(rec.mb_done),
-                                          rec.last_seen)
-                    if msg.done:
-                        self._set_astate(rec, AttemptState.COMPLETED)
-                    # progress log: offset fraction + resumable data state
-                    log = ProgressLog(
-                        task_id=msg.task_id, node_id=msg.host_id,
-                        offset=msg.mb_done / max(msg.mb_total, 1),
-                        handle=msg.data_state)
-                    if self.speculator is not None:
-                        self.speculator.record_progress_log(log)
-                    if self._ref_spec is not None:
-                        self._ref_spec.record_progress_log(log)
-            elif isinstance(msg, AckMessage):
-                self._pending.pop(msg.attempt_id, None)
+        # Gather: wait until every microbatch's gradient is in, with the
+        # policy ticks and redeliveries inside the wait.
+        with span("runtime.gather"):
+            last_tick = 0.0
+            last_grad = self.clock.time()
+            auto = max(60.0, 30 * self.cfg.restart_timeout)
+            deadline = self.clock.time() + (self.cfg.step_deadline or auto)
+            while len(grads) < self.n_shards * M:
+                now = self.clock.time()
+                if now > deadline:
+                    self._abort_inflight(step, attempts)
+                    return False, mb_executed, {}, "deadline exceeded"
+                if len(self.live_hosts()) < self._quorum():
+                    self._abort_inflight(step, attempts)
+                    return False, mb_executed, {}, "quorum lost"
+                try:
+                    msg = self.queue.get(timeout=0.02)
+                except queue.Empty:
+                    msg = None
+                if isinstance(msg, GradMessage):
+                    if msg.step != step:
+                        continue  # stale stream of a previous step's loser
+                    key = (msg.shard_id, msg.mb_index)
+                    mb_executed += 1
+                    rec = attempts.get(msg.attempt_id)
+                    if rec is not None:
+                        rec.last_seen = self.clock.time()
+                    if key not in grads:  # exactly-once: first writer wins
+                        grads[key] = msg.grads
+                        for k, v in msg.metrics.items():
+                            metric_acc[k] = metric_acc.get(k, 0.0) + v
+                        tid = f"s{step}_grad{msg.shard_id:03d}"
+                        t = tasks.get(tid)
+                        if t is not None:
+                            t["last_grad"] = self.clock.time()
+                            last_grad = t["last_grad"]
+                            # Coverage decides completion — never an
+                            # attempt's own done-claim, which can vanish
+                            # in transit.
+                            if not t["done"]:
+                                have = sum(1 for (s, _m) in grads
+                                           if s == msg.shard_id)
+                                if have >= M:
+                                    self._mark_task_done(tasks, tid)
+                elif isinstance(msg, ProgressMessage):
+                    if msg.step != step:
+                        continue
+                    rec = attempts.get(msg.attempt_id)
+                    if rec is not None and rec.state == AttemptState.RUNNING:
+                        # max(): chaos can reorder adjacent reports
+                        rec.mb_done = max(rec.mb_done, msg.mb_done)
+                        rec.last_seen = self.clock.time()
+                        if self.arr is not None and rec.row >= 0:
+                            self.arr.sync_row(rec.row, float(rec.mb_done),
+                                              rec.last_seen)
+                        if msg.done:
+                            self._set_astate(rec, AttemptState.COMPLETED)
+                        # progress log: offset + resumable data state
+                        log = ProgressLog(
+                            task_id=msg.task_id, node_id=msg.host_id,
+                            offset=msg.mb_done / max(msg.mb_total, 1),
+                            handle=msg.data_state)
+                        if self.speculator is not None:
+                            self.speculator.record_progress_log(log)
+                        if self._ref_spec is not None:
+                            self._ref_spec.record_progress_log(log)
+                elif isinstance(msg, AckMessage):
+                    self._pending.pop(msg.attempt_id, None)
 
-            now = self.clock.time()
-            self._pump_retries(step, now, tasks, attempts, grads,
-                               shard_states, recoveries)
-            if now - last_tick >= self.cfg.spec_interval:
-                last_tick = now
-                if self.speculator is not None:
-                    self._bino_tick(step, tasks, attempts, grads,
-                                    shard_states, recoveries)
-                else:
-                    aborted = self._restart_tick(tasks, attempts,
-                                                 recoveries, last_grad)
-                    if aborted:
-                        self._finish_job(step)
-                        return False, mb_executed, {}, "restart"
+                now = self.clock.time()
+                self._pump_retries(step, now, tasks, attempts, grads,
+                                   shard_states, recoveries)
+                if now - last_tick >= self.cfg.spec_interval:
+                    last_tick = now
+                    if self.speculator is not None:
+                        with span("runtime.bino_tick"):
+                            self._bino_tick(step, tasks, attempts, grads,
+                                            shard_states, recoveries)
+                    else:
+                        aborted = self._restart_tick(tasks, attempts,
+                                                     recoveries, last_grad)
+                        if aborted:
+                            self._finish_job(step)
+                            return False, mb_executed, {}, "restart"
 
         # ---- reduce: deterministic ordered sum + optimizer apply -------
-        ordered = [grads[k] for k in sorted(grads)]
         denom = float(self.n_shards * M)
-        total = jax.tree.map(
-            lambda *xs: sum(x.astype(np.float32) if hasattr(x, "astype")
-                            else x for x in xs) / denom, *ordered)
-        # Release the per-microbatch trees before the optimizer builds the
-        # new state: at full model width they are most of the device.
-        del ordered
-        grads.clear()
-        self.state = self.apply_fn(self.state, total)
-        for s in range(self.n_shards):
-            self.datastates[s] = self.datastates[s].advance(M)
-        for h in self.live_hosts():
-            self.hosts[h].set_params(self.state["params"])
+        with span("runtime.reduce"):
+            ordered = [grads[k] for k in sorted(grads)]
+            total = jax.tree.map(
+                lambda *xs: sum(x.astype(np.float32) if hasattr(x, "astype")
+                                else x for x in xs) / denom, *ordered)
+            # Release the per-microbatch trees before the optimizer builds
+            # the new state: at full model width they are most of the
+            # device.
+            del ordered
+            grads.clear()
+        with span("runtime.apply"):
+            self.state = self.apply_fn(self.state, total)
+            for s in range(self.n_shards):
+                self.datastates[s] = self.datastates[s].advance(M)
+            for h in self.live_hosts():
+                self.hosts[h].set_params(self.state["params"])
         metrics = {k: v / denom for k, v in metric_acc.items()}
         self._finish_job(step)
         return True, mb_executed, metrics, "ok"
@@ -661,10 +673,16 @@ class Coordinator:
                 if self.obs is not None:
                     self.obs.emit(K_DETECT, a=self._host_pos[hid], b=0,
                                   obj="silent-at-rollback")
+                self._observe_silence(now - hb.get(hid, 0.0))
                 self.metrics.counter("expiry_declares").inc()
                 recoveries.append(
                     f"host {hid} silent {now - hb.get(hid, 0.0):.2f}s "
                     "at rollback -> declared dead")
+
+    def _observe_silence(self, silent: float) -> None:
+        """At a host's declaration as lost: seconds since its last
+        heartbeat, the time the detector took."""
+        self.metrics.histogram("detect_silence_s").observe(silent)
 
     # -- bino recovery ----------------------------------------------------
     def _snapshot(self, step, tasks, attempts, grads) -> ClusterSnapshot:
@@ -742,6 +760,8 @@ class Coordinator:
                 if self.obs is not None:
                     self.obs.emit(K_DETECT, a=self._host_pos[act.node_id],
                                   b=1, obj=act.reason)
+                self._observe_silence(
+                    snap.now - snap.nodes[act.node_id].last_heartbeat)
                 self.metrics.counter("detections").inc()
                 recoveries.append(f"host {act.node_id} declared failed "
                                   f"({act.reason})")
@@ -896,6 +916,7 @@ class Coordinator:
             if self.obs is not None:
                 self.obs.emit(K_DETECT, a=self._host_pos[hid], b=0,
                               obj="gang-timeout")
+            self._observe_silence(now - hb.get(hid, 0.0))
             self.metrics.counter("expiry_declares").inc()
             recoveries.append(
                 f"host {hid} timed out ({self.cfg.restart_timeout}s) "

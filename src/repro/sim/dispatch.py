@@ -39,7 +39,6 @@ Since ISSUE 9 the plane is multi-tenant (tenant = job):
 """
 from __future__ import annotations
 
-import time
 from collections import OrderedDict, deque
 from typing import TYPE_CHECKING, Deque, Dict, List, Optional, Tuple
 
@@ -82,14 +81,11 @@ class Dispatcher:
                     exists and the batch is at least ``bulk_min`` deep.
     ``weights``   — optional tenant → DRR quantum map (containers of
                     credit per rotation cycle; default 1.0 each).
-    ``profile``   — accumulate wall-clock in ``decision_wall`` around
-                    each placement pass (benchmarks/perf_dispatch.py).
     """
 
     def __init__(self, sim: "Simulation", *, fair: bool = True,
                  bulk: Optional[bool] = None, bulk_min: int = _BULK_MIN,
-                 weights: Optional[Dict[str, float]] = None,
-                 profile: bool = False):
+                 weights: Optional[Dict[str, float]] = None):
         self.sim = sim
         self.fair = fair
         self.bulk = bulk
@@ -98,7 +94,6 @@ class Dispatcher:
         for jid, w in self.weights.items():
             if not w > 0:
                 raise ValueError(f"tenant weight must be > 0: {jid}={w}")
-        self.profile = profile
         # tenant (job_id) → FIFO of its pending launches, in arrival
         # order of first demand; "" is the shared legacy queue
         # (fair=False).
@@ -114,7 +109,6 @@ class Dispatcher:
         self.n_bulk_passes = 0
         self.n_scalar_passes = 0
         self.n_skipped_passes = 0   # zero-free early-outs
-        self.decision_wall = 0.0
 
     # ------------------------------------------------------------------
     # Queue maintenance
@@ -206,7 +200,6 @@ class Dispatcher:
     def dispatch(self) -> None:
         if not self._total:
             return
-        t0 = time.perf_counter() if self.profile else 0.0
         arr = self.sim.arrays
         # Grant budget: a pass can grant at most the cluster's free
         # slots, and with the eager task_done/job_done purge every
@@ -226,8 +219,6 @@ class Dispatcher:
                 # O(pending) full rescan that was the bulk of the
                 # PR 7 10 000-node dispatch wall.
                 self.n_skipped_passes += 1
-                if self.profile:
-                    self.decision_wall += time.perf_counter() - t0
                 return
         if self.bulk is None:
             use_bulk = arr is not None and self._total >= self.bulk_min
@@ -239,8 +230,6 @@ class Dispatcher:
         else:
             self.n_scalar_passes += 1
             self._run_pass(self._try_scalar, budget)
-        if self.profile:
-            self.decision_wall += time.perf_counter() - t0
 
     def _run_pass(self, try_place, budget: Optional[int]) -> None:
         """One placement pass: every queued request is visited at most

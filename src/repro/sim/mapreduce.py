@@ -52,6 +52,7 @@ from repro.core.types import (
     TaskView,
 )
 from repro.net.base import make_network
+from repro.obs.metrics import span
 from repro.obs.trace import (
     ACT_KILL,
     ACT_MARK_FAILED,
@@ -1217,10 +1218,12 @@ class Simulation:
 
     def _speculator_tick(self) -> None:
         self.sched.watchdog()
-        t0 = time.perf_counter()
-        snap = self._snapshot()
-        actions = self.speculator.assess(snap)
-        self.assess_wall += time.perf_counter() - t0
+        with span("sim.tick", tick=self.assess_ticks):
+            t0 = time.perf_counter()
+            with span("sim.snapshot"):
+                snap = self._snapshot()
+            actions = self.speculator.assess(snap)
+            self.assess_wall += time.perf_counter() - t0
         self.assess_ticks += 1
         self.actions_emitted += len(actions)
         rec = self._act_rec

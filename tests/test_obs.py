@@ -38,6 +38,7 @@ from repro.obs import (
     comparable_core,
     instrument_drain,
     scorecard,
+    span,
     to_chrome_trace,
     trace_diff,
     write_chrome_trace,
@@ -316,3 +317,49 @@ def test_scorecard_identical_across_worlds(script):
     assert snap["detections"] == len(rec_rt.by_kind(K_DETECT)[
         rec_rt.by_kind(K_DETECT)["b"] == 1])
     assert snap["recoveries"] > 0
+
+
+# ---------------------------------------------------------------------------
+# 7. Host spans on the profiler's clock (DESIGN.md §18.7)
+# ---------------------------------------------------------------------------
+def _span_names(log_dir):
+    import glob
+
+    import jax
+    path = sorted(glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True))[-1]
+    data = jax.profiler.ProfileData.from_file(path)
+    return [ev.name for plane in data.planes for line in plane.lines
+            for ev in line.events if ev.name.startswith("repro.")]
+
+
+def test_span_names_the_program_under_a_trace(tmp_path):
+    import jax
+    with span("sim.tick", tick=7):
+        pass                          # no trace: nothing recorded
+    with jax.profiler.trace(str(tmp_path)):
+        with span("sim.tick", tick=7):
+            with span("accel.wait"):
+                pass
+    assert _span_names(tmp_path) == ["repro.sim.tick", "repro.accel.wait"]
+
+
+def test_spans_move_no_simulated_event(tmp_path):
+    """A profiler trace around a run records the tick's spans, the
+    device path's among them, and changes nothing the simulation
+    decides: same action trace, same launches, same results."""
+    import jax
+    fault = _script_fault([("crash", 7, 0.45, 0.0)])
+    off = run_traced("batch", "bino", fault, seed=3, gb=1.0,
+                     assess_backend="jax")
+    with jax.profiler.trace(str(tmp_path)):
+        on = run_traced("batch", "bino", fault, seed=3, gb=1.0,
+                        assess_backend="jax")
+    assert_runs_equivalent([off, on], ["profiler-off", "profiler-on"])
+    assert off.sim.action_trace == on.sim.action_trace
+    names = set(_span_names(tmp_path))
+    assert {"repro.sim.tick", "repro.sim.snapshot", "repro.core.glance",
+            "repro.core.plan", "repro.accel.spatial_hits",
+            "repro.accel.upload", "repro.accel.launch", "repro.accel.wait",
+            "repro.accel.fetch"} <= names
+    backend = on.sim.speculator.backend
+    assert backend.fetch_bytes > 0

@@ -142,6 +142,21 @@ def test_differential_decisions_under_straggler(fault_free):
     assert np.array_equal(vec_ff, vec)
 
 
+@pytest.mark.parametrize("policy,floor", [("bino", 0.2), ("restart", 1.5)])
+def test_detect_silence_observed_at_each_declaration(policy, floor):
+    """Each host declared lost adds its silence at declaration to
+    ``detect_silence_s``: past the responsive window (4 heartbeats) under
+    bino, past ``restart_timeout`` under the gang baseline."""
+    _, _, coord = _run(policy, script=PINNED_SCRIPTS["crash"],
+                       fake_clock=True, restart_timeout=1.5,
+                       repair_timeout=0.5)
+    snap = coord.metrics.snapshot()
+    declared = snap.get("detections", 0) + snap.get("expiry_declares", 0)
+    assert declared >= 1
+    assert snap["detect_silence_s_n"] == declared
+    assert snap["detect_silence_s_min"] > floor
+
+
 def test_gang_restart_also_exact_but_slower(fault_free):
     vec_ff, _ = fault_free
     vec, reports, _ = _run("restart", script=PINNED_SCRIPTS["crash"],
