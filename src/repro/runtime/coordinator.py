@@ -115,6 +115,28 @@ class StepWedged(RuntimeError):
         self.step = step
 
 
+@jax.jit
+def ordered_mean(trees, denom):
+    """The reduce barrier's mean: per leaf ``(f32(g0) + f32(g1) + ...) /
+    denom``, summed left to right over ``trees`` (the gradient trees in
+    sorted (shard, microbatch) key order), returning float32 leaves.
+
+    One compiled program, so the whole tree is one pass over memory; XLA
+    does not reassociate float adds, so the order, and with it every bit
+    of the result, is the one the list gives. ``denom`` is an operand,
+    not a constant: a constant divisor is rewritten as a multiply by its
+    inexact reciprocal, which moves the last bit. The program specialises
+    on the tree structure and ``len(trees)``, both fixed for a run, so it
+    compiles once.
+    """
+    def leaf(*xs):
+        acc = xs[0].astype(np.float32)
+        for x in xs[1:]:
+            acc = acc + x.astype(np.float32)
+        return acc / denom
+    return jax.tree.map(leaf, *trees)
+
+
 @dataclasses.dataclass(frozen=True)
 class RuntimeConfig:
     n_hosts: int = 4
@@ -619,9 +641,7 @@ class Coordinator:
         denom = float(self.n_shards * M)
         with span("runtime.reduce"):
             ordered = [grads[k] for k in sorted(grads)]
-            total = jax.tree.map(
-                lambda *xs: sum(x.astype(np.float32) if hasattr(x, "astype")
-                                else x for x in xs) / denom, *ordered)
+            total = ordered_mean(ordered, np.float32(denom))
             # Release the per-microbatch trees before the optimizer builds
             # the new state: at full model width they are most of the
             # device.

@@ -15,6 +15,7 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -218,6 +219,86 @@ def test_quorum_loss_raises_step_wedged():
             t.run(2)
     finally:
         t.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The reduce barrier's compiled ordered mean (``ordered_mean``) against the
+# eager formula it replaced: the same adds in the same order, so the same
+# bits.
+# ---------------------------------------------------------------------------
+def _eager_mean(trees, denom):
+    return jax.tree.map(
+        lambda *xs: sum(x.astype(np.float32) for x in xs) / denom, *trees)
+
+
+def _grad_tree(rng):
+    """Mixed bf16 and f32 leaves, a scalar leaf, one nested group."""
+    return {
+        "embed": jnp.asarray(rng.standard_normal((17, 8)), jnp.bfloat16),
+        "layers": {
+            "w": jnp.asarray(rng.standard_normal((3, 5, 4)), jnp.float32),
+            "b": jnp.asarray(rng.standard_normal((3, 5)), jnp.bfloat16)},
+        "norm": jnp.asarray(rng.standard_normal(8) * 1e-3, jnp.float32),
+        "scale": jnp.asarray(rng.standard_normal(()), jnp.float32),
+    }
+
+
+def _reinserted(tree):
+    """The same tree, every dict's keys inserted in reverse order."""
+    if isinstance(tree, dict):
+        return {k: _reinserted(tree[k]) for k in reversed(list(tree))}
+    return tree
+
+
+def _bits(tree):
+    return [np.asarray(l).view(np.uint32).tobytes()
+            for l in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("case", ["bitwise", "signed_zero", "key_order"])
+@pytest.mark.parametrize("n", [3, 4, 6])
+def test_ordered_mean_matches_eager_sum(n, case):
+    from repro.runtime.coordinator import ordered_mean
+    denom = float(n)
+    trees = [_grad_tree(np.random.default_rng(100 + i)) for i in range(n)]
+    if case == "signed_zero":
+        # every tree holds -0.0 in one place: the eager sum's leading
+        # ``0 +`` gave +0.0 there, the compiled sum keeps -0.0
+        trees = [jax.tree.map(lambda x: x.at[(0,) * x.ndim].set(-0.0), t)
+                 for t in trees]
+    got = ordered_mean(trees, np.float32(denom))
+    want = _eager_mean(trees, denom)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == np.float32 and g.shape == w.shape
+    if case == "signed_zero":
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+        assert _bits(got) != _bits(want)
+    else:
+        assert _bits(got) == _bits(want)
+    if case == "key_order":
+        flipped = [_reinserted(t) for t in trees]
+        assert list(flipped[0]) != list(trees[0])
+        assert _bits(ordered_mean(flipped, np.float32(denom))) == _bits(got)
+
+
+def test_reduce_compiles_once():
+    """The ordered mean compiles in the first step and never again, and
+    steps after the first compile nothing at all."""
+    from repro.compile_cache import CompileCounter
+    from repro.runtime.coordinator import ordered_mean
+    CompileCounter.install()
+    ordered_mean.clear_cache()
+    marks = {}
+
+    def on_step(i, trainer):
+        marks[i] = CompileCounter.snapshot()
+
+    _, reports, _ = _run("bino", steps=4, inject=on_step)
+    assert len(reports) == 4
+    assert ordered_mean._cache_size() == 1
+    assert CompileCounter.since(marks[1])["executables"] == 0
 
 
 # ---------------------------------------------------------------------------
